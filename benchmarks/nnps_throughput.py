@@ -32,7 +32,8 @@ pollute the history.
 
 ``--n 1000000`` reaches the paper's 1M-particle case (expect minutes per
 backend on CPU; tiers above 200k run the production xla/fp16 combo only,
-and a tier that OOMs is recorded as a skipped row with the reason);
+and a tier that OOMs is recorded as a failed row with the reason, and
+the run then exits non-zero);
 ``--quick`` runs the 8k case only. ``--dynamic`` adds dam-break rows
 with a Verlet skin — the collapse keeps the rebuild ``lax.cond`` firing
 inside the timed scan, so their steps/sec is the AMORTIZED physics +
@@ -243,27 +244,28 @@ def main(
     ``case_name`` benchmarks any registered scenario (BENCH records are
     tagged with it); ``dynamic_sizes`` adds dam-break rows with a
     Verlet skin — rebuilds fire inside the timed scan, so their
-    steps/sec is the amortized (physics + rebuild) throughput. Tiers
-    that fail to build or run (e.g. an OOM at the 1M tier) are recorded
-    as skipped rows with the reason, never crash the run."""
+    steps/sec is the amortized (physics + rebuild) throughput. A tier
+    that fails to build or run (e.g. an OOM at the 1M tier) is recorded
+    as a failed row with the reason, the remaining tiers still run, and
+    the call then raises: a failed tier fails the benchmark."""
     if sizes is None:
         targets = [8000, 64000] if full else [8000]
         sizes = [(t, default_steps(t)) for t in targets]
     runs = [("reference", "fp32"), ("xla", "fp32"), ("xla", "fp16")]
-    rows, skipped = [], []
+    rows, failed = [], []
 
     def attempt(n_target, backend, nsteps, **kw):
         try:
             rows.append(run_case(n_target, backend, nsteps, **kw))
-        except Exception as e:  # best-effort tiers: record, don't crash
+        except Exception as e:  # noqa: BLE001 - record, run the rest, raise below
             reason = f"{type(e).__name__}: {e}"[:300]
-            skipped.append({
+            failed.append({
                 "case": kw.get("case_name", case_name),
                 "dynamic": kw.get("dynamic", False),
                 "n_target": n_target, "backend": backend,
-                "records": kw.get("records", "fp16"), "skipped": reason,
+                "records": kw.get("records", "fp16"), "failed": reason,
             })
-            emit("step_throughput_skipped", skipped[-1])
+            emit("step_throughput_failed", failed[-1])
 
     for n_target, nsteps in sizes:
         combos = runs if n_target <= BIG_TIER else [("xla", "fp16")]
@@ -283,23 +285,14 @@ def main(
         attempt(sizes[0][0], "xla", sizes[0][1], skin_frac_hc=0.0)
 
     if not rows:
-        # every tier was skipped (e.g. a 1M-only invocation that OOMed):
-        # the skip rows ARE the record — never crash past them
-        record = {
+        # every tier failed (e.g. a 1M-only invocation that OOMed)
+        _finish({
             "label": "rebuild_round",
             "case": case_name,
             "backend": jax.default_backend(),
             "cpu_count": os.cpu_count(),
             "cases": [],
-            "skipped": skipped,
-        }
-        if append:
-            _append_record(record)
-        if out:
-            with open(out, "w") as f:
-                json.dump(record, f, indent=2)
-        emit("step_throughput_summary", {"skipped": len(skipped)})
-        return record
+        }, failed, append, out, {})
 
     def pick(n_target, backend, records):
         for r in rows:
@@ -348,14 +341,24 @@ def main(
             2,
         ),
     }
-    if skipped:
-        record["skipped"] = skipped
+    return _finish(record, failed, append, out, speedups)
+
+
+def _finish(record, failed, append, out, summary):
+    """Write the run record (failed tiers included, with their reasons),
+    then raise if any tier failed."""
+    if failed:
+        record["failed"] = failed
     if append:
         _append_record(record)
     if out:
         with open(out, "w") as f:
             json.dump(record, f, indent=2)
-    emit("step_throughput_summary", speedups)
+    emit("step_throughput_summary", {**summary, "failed": len(failed)})
+    if failed:
+        raise RuntimeError(
+            f"{len(failed)} benchmark tier(s) failed: "
+            + "; ".join(f["failed"] for f in failed))
     return record
 
 

@@ -9,6 +9,8 @@ import argparse
 import time
 import traceback
 
+from repro.runtime import compile_cache
+
 from benchmarks import (fig7_scaling, fig13_precision, lm_roofline,
                         nnps_throughput, table1_circle,
                         table2_neighbor_accuracy, table3_gradient,
@@ -35,6 +37,7 @@ def main():
                     help="comma-separated module keys")
     args = ap.parse_args()
     only = [s for s in args.only.split(",") if s]
+    compile_cache.enable()
     failures = 0
     for name, mod in MODULES.items():
         if only and name not in only:

@@ -6,8 +6,9 @@ a cell are contiguous; row-major cell order keeps spatial neighbors close
 in HBM). Row C is a sentinel empty cell: out-of-domain neighborhood slots
 point at it, so the kernels never branch on validity.
 
-``interpret`` defaults to True on CPU (this container) and should be False
-on real TPU. All wrappers are shape-polymorphic over (C, cap, d, M).
+``interpret=None`` resolves through :func:`default_interpret`: compiled
+on TPU, interpreted on every other backend (tests on the CPU). All
+wrappers are shape-polymorphic over (C, cap, d, M).
 """
 from __future__ import annotations
 
@@ -116,6 +117,49 @@ def unpack_per_particle(
     each particle's slot via ``cells.from_cell_major``.
     """
     return cells_lib.from_cell_major(binning, table[: binning.table.shape[0]])
+
+
+def cell_tables(
+    rows16: Array,  # (N, F16) u16 cell-sorted 16-bit record rows
+    rows32: Array,  # (N, F32) f32 cell-sorted fp32 rows
+    counts: Array,  # (C,) int32 per-cell occupancy
+    fill32: Array,  # (F32,) f32 empty-slot fill per fp32 column
+    *,
+    cap: int,
+) -> tuple[Array, Array]:
+    """Cell-major tables from cell-sorted rows: one window per cell.
+
+    The persistent pipeline's arrays are cell-sorted, so cell c's
+    particles are the contiguous rows ``starts[c] .. starts[c] +
+    counts[c] - 1`` (the counting-sort invariant): each cell's tile is
+    the ``cap``-row window at ``starts[c]`` of a record slab, masked past
+    the occupancy — one windowed gather per slab instead of one id-table
+    gather per field. Returns ``(t16 (C+1, F16, cap) u16, t32 (C+1,
+    F32, cap) f32)``: row C is the sentinel empty cell (fp32 columns hold
+    their fill so denominator fields stay finite).
+    """
+    n = rows16.shape[0]
+    starts = jnp.concatenate(
+        [cells_lib.exclusive_cumsum(counts), jnp.full((1,), n, jnp.int32)]
+    )
+    counts_s = jnp.concatenate(
+        [counts.astype(jnp.int32), jnp.zeros((1,), jnp.int32)]
+    )
+    slot = jnp.arange(cap, dtype=jnp.int32)[None, :]
+    occ = (slot < counts_s[:, None])[..., None]  # (C+1, cap, 1)
+
+    def windows(rows):
+        # cap rows of padding: the sentinel's window (and a full last
+        # cell's) never reads past the slab
+        pad = jnp.concatenate([rows, jnp.zeros((cap,) + rows.shape[1:],
+                                               rows.dtype)])
+        return jax.vmap(
+            lambda s: jax.lax.dynamic_slice_in_dim(pad, s, cap, 0)
+        )(starts)  # (C+1, cap, F)
+
+    t16 = jnp.where(occ, windows(rows16), 0)
+    t32 = jnp.where(occ, windows(rows32), fill32[None, None, :])
+    return t16.transpose(0, 2, 1), t32.transpose(0, 2, 1)
 
 
 # --------------------------------------------------------------------------
@@ -320,9 +364,9 @@ def rcll_force_particles(
     REQUIRES the persistent pipeline's PACKED binning (the per-particle
     arrays are cell-sorted and ``binning.table`` holds consecutive
     packed ids): the cell-major tiles are then contiguous row slices,
-    built by the one-sweep cell-pack kernel (``kernels/cell_pack.py``)
-    from two record slabs — one 16-bit row ``[rel | shift | v]`` and
-    one fp32 row ``[1/ρ]`` — instead of one id-table gather per field.
+    built by :func:`cell_tables` from two record slabs — one 16-bit row
+    ``[rel | shift | v]`` and one fp32 row ``[1/ρ]`` — instead of one
+    id-table gather per field.
     ``m_table``/``m_scale``: optionally precomputed static mass tile
     (:func:`mass_table`) — the solver rebuilds it only when the packed
     order changes, so the per-step refresh touches exactly the
@@ -339,7 +383,6 @@ def rcll_force_particles(
     """
     from repro.core import fused  # shared mass normalizer
     from repro.core import scheme as scheme_lib
-    from repro.kernels import cell_pack
 
     if scheme is None:
         if c0 is None:
@@ -380,15 +423,12 @@ def rcll_force_particles(
     else:
         cols32.append(v.astype(jnp.float32))
         fill32 += [0.0] * d
-    starts = cells_lib.exclusive_cumsum(binning.counts)
-    t16, t32, _ = cell_pack.cell_tables(
+    t16, t32 = cell_tables(
         jnp.concatenate(cols16, axis=1),
         jnp.concatenate(cols32, axis=1),
-        starts,
         binning.counts,
         jnp.asarray(fill32, jnp.float32),
         cap=binning.table.shape[1],
-        interpret=interpret,
     )
     o16 = d if rel_half else 0  # 16-bit slab offset past rel
     o32 = 1 + (0 if rel_half else d)  # fp32 slab offset past inv, rel
@@ -404,11 +444,10 @@ def rcll_force_particles(
     else:
         v_t = t32[:, o32:o32 + d]
     inv_t = t32[:, 0]
-    m_t = m_table
-    offs = tuple(map(tuple, cells_lib.neighbor_cell_offsets(domain.dim)))
     drho_t, acc_t = rcll_force.rcll_force(
-        rel_t, shift_t, v_t, m_t, inv_t, nb_with_sentinel(domain),
-        offs=offs,
+        rel_t, shift_t, v_t, m_table, inv_t,
+        ncells=tuple(domain.ncells),
+        periodic=tuple(domain.periodic),
         hc_phys=tuple(domain.cell_sizes),
         h=domain.h,
         dim=domain.dim,

@@ -9,20 +9,34 @@ B-spline gradient in place, and accumulates the continuity AND momentum
 sums directly into fp32 VMEM accumulators indexed by the self cell — the
 full WCSPH right-hand side in ONE pass over the neighbor tiles (the
 solver integrates the standard explicit scheme, so both sums read the
-same state). Layout, blocking, and scalar-prefetched neighbor ids are
-identical to ``nnps_pairwise.py`` / ``sph_gradient.py`` (shared helpers
-in ``kernels/tiling.py``); the pair physics goes through the same
-primitives as the reference path (``core/bspline.py`` / ``core/sph.py``).
+same state). The tile math is shared with ``nnps_pairwise.py`` /
+``sph_gradient.py`` (``kernels/tiling.py``); the pair physics goes
+through the same primitives as the reference path (``core/bspline.py``
+/ ``core/sph.py``).
+
+Mosaic layout (what the TPU compiler accepts at 1M particles):
+
+  * grid (C, 3^d), one (self cell, neighbor cell) pair per step; every
+    per-cell operand is a ``(C+1, rows, cap)`` table read in
+    ``(1, rows, cap)`` blocks, so the block's last two dims equal the
+    array's (scalar rows are ``(C+1, 1, cap)``);
+  * the neighbor cell id is computed in the BlockSpec index map from
+    ``c``, ``k`` and the static grid (:func:`neighbor_cell`) — no
+    ``(C, 3^d)`` table is prefetched into SMEM, which cannot hold one
+    past ~2k cells; the 3^d x d offsets ride SMEM;
+  * 16-bit floats stream as their int16 words (Mosaic has no float16
+    vector load) and decode to fp32 in registers with integer ops,
+    bit-identical to ``astype(float32)`` (``tiling.bits16_to_f32``).
 
 Half-width tile streams (the bandwidth round). The kernel's per-tile
 inputs are sized by ``PrecisionPolicy.records``:
 
   * coordinates stream as the RAW storage-dtype relative coordinate
-    (fp16 — lossless, it IS the RCLL state) plus an int8 stale-cell
+    (fp16 — lossless, it IS the RCLL state) plus an int16 stale-cell
     shift; the re-anchor ``rel' = rel + 2·(cell_now − cell_stale)``
     happens in fp32 registers (``tiling.tile_phys_disp_shifted``) — an
-    exact decode at 3 bytes/axis instead of a pre-shifted fp32
-    coordinate's 4;
+    exact decode at 4 bytes/axis (fp16 word + int16 shift word), the
+    same bytes as a pre-shifted fp32 coordinate;
   * v and m stream in the records dtype (fp16/bf16 production, fp32
     oracle) and upcast to fp32 in-register;
   * the density tier streams fp32 as the RECIPROCAL 1/ρ (full fp32
@@ -69,29 +83,29 @@ Array = jnp.ndarray
 
 
 def _force_kernel(
-    # scalar prefetch
-    nb_ref,
     # inputs
-    off_ref,  # (1, d) neighborhood offset for this k
-    rel_i_ref,  # (1, d, cap) self cell (raw storage-dtype rel)
+    off_ref,  # (M*d,) f32 neighborhood offsets, SMEM
+    rel_i_ref,  # (1, d, cap) self cell: rel words (16-bit) or f32
     rel_j_ref,  # (1, d, cap) neighbor cell
-    shift_i_ref,  # (1, d, cap) int8 stale-cell shift
+    shift_i_ref,  # (1, d, cap) int16 stale-cell shift
     shift_j_ref,  # (1, d, cap)
-    v_i_ref,  # (1, d, cap) records dtype
+    v_i_ref,  # (1, d, cap) records words (16-bit) or f32
     v_j_ref,  # (1, d, cap)
-    m_j_ref,  # (1, cap) records dtype (0 in empty slots)
-    inv_i_ref,  # (1, cap) f32 reciprocal density (1/rho0 in empty slots)
-    inv_j_ref,  # (1, cap) f32
+    m_j_ref,  # (1, 1, cap) records words or f32 (0 in empty slots)
+    inv_i_ref,  # (1, 1, cap) f32 reciprocal density (1/rho0 in empty slots)
+    inv_j_ref,  # (1, 1, cap) f32
     # outputs (indexed by c only -> accumulated across the k axis)
-    drho_ref,  # (1, cap) f32
+    drho_ref,  # (1, 1, cap) f32
     acc_ref,  # (1, d, cap) f32
     *,
     hc_phys: tuple,
     h: float,
     dim: int,
+    rel_dtype,
+    records_dtype,
     scheme: scheme_lib.Scheme,
 ):
-    _, k = pl.program_id(0), pl.program_id(1)
+    k = pl.program_id(1)
     d = rel_i_ref.shape[1]
 
     @pl.when(k == 0)
@@ -99,25 +113,27 @@ def _force_kernel(
         drho_ref[...] = jnp.zeros_like(drho_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    off_k = [off_ref[k * d + a] for a in range(d)]
     disp, r2 = tiling.tile_phys_disp_shifted(
-        rel_i_ref[0], rel_j_ref[0], shift_i_ref[0], shift_j_ref[0],
-        off_ref[0], hc_phys,
+        tiling.decode_f32(rel_i_ref[0], rel_dtype),
+        tiling.decode_f32(rel_j_ref[0], rel_dtype),
+        tiling.decode_f32(shift_i_ref[0], jnp.int16),
+        tiling.decode_f32(shift_j_ref[0], jnp.int16),
+        off_k, hc_phys,
     )
     coef = bspline.dw_over_r(jnp.sqrt(r2), h, dim)
 
-    mj = m_j_ref[0].astype(jnp.float32)[None, :]
-    inv_i = inv_i_ref[0][:, None]
-    inv_j = inv_j_ref[0][None, :]
-    por2_i = scheme.por2_inv(inv_i_ref[0])
-    por2_j = scheme.por2_inv(inv_j_ref[0])
+    v_i = tiling.decode_f32(v_i_ref[0], records_dtype)
+    v_j = tiling.decode_f32(v_j_ref[0], records_dtype)
+    mj = tiling.decode_f32(m_j_ref[0, 0], records_dtype)[None, :]
+    inv_i = inv_i_ref[0, 0][:, None]
+    inv_j = inv_j_ref[0, 0][None, :]
+    por2_i = scheme.por2_inv(inv_i_ref[0, 0])
+    por2_j = scheme.por2_inv(inv_j_ref[0, 0])
     # Pair velocity deltas and dv·disp first: the scheme's ∇W-channel
     # coefficient (pressure + optional artificial viscosity) needs the
     # full dot product before the per-axis accumulation loop.
-    dv = [
-        v_i_ref[0, a].astype(jnp.float32)[:, None]
-        - v_j_ref[0, a].astype(jnp.float32)[None, :]
-        for a in range(d)
-    ]
+    dv = [v_i[a][:, None] - v_j[a][None, :] for a in range(d)]
     dv_dot_disp = jnp.zeros_like(r2)
     for a in range(d):
         dv_dot_disp += dv[a] * disp[a]
@@ -138,84 +154,122 @@ def _force_kernel(
         dterm += scheme.drho_pair_term(
             mj, inv_i, inv_j, coef * r2, r2, h=h
         )
-    drho_ref[...] += jnp.sum(dterm, axis=1)[None]
+    drho_ref[0, 0] += jnp.sum(dterm, axis=1)
 
 
-def _cell_block(d, cap):
-    return pl.BlockSpec((1, d, cap), lambda c, k, nb: (c, 0, 0))
+def neighbor_cell(c, k, *, ncells: tuple, periodic: tuple):
+    """Flat id of the k-th 3^dim neighbor of flat cell ``c``.
+
+    Scalar integer arithmetic on the static grid (row-major, last axis
+    fastest; offsets in ``cells.neighbor_cell_offsets`` order): periodic
+    axes wrap, out-of-domain offsets map to the sentinel cell
+    ``prod(ncells)``. It runs inside the BlockSpec index map, so no
+    O(C) neighbor table is ever streamed or held in SMEM.
+    """
+    dim = len(ncells)
+    total = int(np.prod(ncells))
+    flat, valid = 0, None
+    for a in range(dim):
+        stride = int(np.prod(ncells[a + 1:]))
+        n = int(ncells[a])
+        x = jax.lax.rem(jax.lax.div(c, stride), n)
+        o = jax.lax.rem(jax.lax.div(k, 3 ** (dim - 1 - a)), 3) - 1
+        y = x + o
+        if periodic[a]:
+            y = jnp.where(y < 0, y + n, jnp.where(y >= n, y - n, y))
+        else:
+            ok = (y >= 0) & (y < n)
+            valid = ok if valid is None else valid & ok
+        flat = flat + y * stride
+    if valid is None:
+        return flat
+    return jnp.where(valid, flat, total)
 
 
-def _nbcell_block(d, cap):
-    return pl.BlockSpec((1, d, cap), lambda c, k, nb: (nb[c, k], 0, 0))
-
-
-def _cell_row(cap):
-    return pl.BlockSpec((1, cap), lambda c, k, nb: (c, 0))
-
-
-def _nbcell_row(cap):
-    return pl.BlockSpec((1, cap), lambda c, k, nb: (nb[c, k], 0))
+def _words(x: Array) -> Array:
+    """16-bit floats travel as their int16 words (Mosaic loads those)."""
+    if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype.itemsize == 2:
+        return jax.lax.bitcast_convert_type(x, jnp.int16)
+    return x
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "offs", "hc_phys", "h", "dim", "scheme", "interpret"
+        "ncells", "periodic", "hc_phys", "h", "dim", "scheme", "interpret"
     ),
 )
 def rcll_force(
-    rel: Array,  # (C, d, cap) raw storage-dtype relative coords
-    shift: Array,  # (C, d, cap) int8 cell shift (cell_now - cell_stale)
-    v: Array,  # (C, d, cap) records dtype
-    m: Array,  # (C, cap) records dtype, 0 in empty slots
-    inv_rho: Array,  # (C, cap) f32 reciprocal density, 1/rho0 in empty slots
-    nb_ids: Array,  # (C, M) int32
+    rel: Array,  # (C+1, d, cap) raw storage-dtype relative coords
+    shift: Array,  # (C+1, d, cap) int16 cell shift (cell_now - cell_stale)
+    v: Array,  # (C+1, d, cap) records dtype
+    m: Array,  # (C+1, cap) records dtype, 0 in empty slots
+    inv_rho: Array,  # (C+1, cap) f32 reciprocal density, 1/rho0 if empty
     *,
-    offs: tuple,  # M x d neighborhood offsets (static)
+    ncells: tuple,  # (d,) cells per axis (static); C = prod(ncells)
+    periodic: tuple,  # (d,) periodic-axis flags (static)
     hc_phys: tuple,  # (d,) physical cell edges (static)
     h: float,
     dim: int,
     scheme: scheme_lib.Scheme,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> tuple[Array, Array]:
     """Fused SPH RHS: (drho (C, cap), acc (C, d, cap)), one tile pass.
 
-    The physics terms (EOS, viscosity channels) come from the static
-    ``scheme`` — the same declarative spec the XLA and reference
-    backends consume (core/scheme.py).
+    Row C of every input table is the sentinel empty cell that
+    out-of-domain neighbors read. The physics terms (EOS, viscosity
+    channels) come from the static ``scheme`` — the same declarative
+    spec the XLA and reference backends consume (core/scheme.py).
     """
-    C, d, cap = rel.shape
-    M = nb_ids.shape[1]
-    offs_arr = jnp.asarray(np.asarray(offs, np.float32).reshape(M, d))
+    from repro.core import cells  # deferred: kernels stay import-light
+
+    c1, d, cap = rel.shape
+    C = int(np.prod(ncells))
+    if c1 != C + 1:
+        raise ValueError(f"tables hold {c1} cells; grid {ncells} needs {C + 1}")
+    offs = cells.neighbor_cell_offsets(dim)
+    M = offs.shape[0]
+    offs_flat = jnp.asarray(offs.astype(np.float32).reshape(M * d))
     kernel = functools.partial(
         _force_kernel,
         hc_phys=tuple(float(x) for x in hc_phys),
         h=float(h),
         dim=int(dim),
+        rel_dtype=jnp.dtype(rel.dtype),
+        records_dtype=jnp.dtype(v.dtype),
         scheme=scheme,
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+    nb = functools.partial(
+        neighbor_cell, ncells=tuple(int(n) for n in ncells),
+        periodic=tuple(bool(p) for p in periodic),
+    )
+
+    def cell_block(rows):
+        return pl.BlockSpec((1, rows, cap), lambda c, k: (c, 0, 0))
+
+    def nbcell_block(rows):
+        return pl.BlockSpec((1, rows, cap), lambda c, k: (nb(c, k), 0, 0))
+
+    rel_w, v_w = _words(rel), _words(v)
+    m_w = _words(m).reshape(c1, 1, cap)
+    inv_w = inv_rho.astype(jnp.float32).reshape(c1, 1, cap)
+    drho, acc = pl.pallas_call(
+        kernel,
         grid=(C, M),
         in_specs=[
-            pl.BlockSpec((1, d), lambda c, k, nb: (k, 0)),
-            _cell_block(d, cap), _nbcell_block(d, cap),  # rel i, j
-            _cell_block(d, cap), _nbcell_block(d, cap),  # shift i, j
-            _cell_block(d, cap), _nbcell_block(d, cap),  # v i, j
-            _nbcell_row(cap),  # m_j
-            _cell_row(cap), _nbcell_row(cap),  # 1/rho i, j
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            cell_block(d), nbcell_block(d),  # rel i, j
+            cell_block(d), nbcell_block(d),  # shift i, j
+            cell_block(d), nbcell_block(d),  # v i, j
+            nbcell_block(1),  # m_j
+            cell_block(1), nbcell_block(1),  # 1/rho i, j
         ],
-        out_specs=[
-            _cell_row(cap),
-            pl.BlockSpec((1, d, cap), lambda c, k, nb: (c, 0, 0)),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
+        out_specs=[cell_block(1), cell_block(d)],
         out_shape=[
-            jax.ShapeDtypeStruct((C, cap), jnp.float32),
+            jax.ShapeDtypeStruct((C, 1, cap), jnp.float32),
             jax.ShapeDtypeStruct((C, d, cap), jnp.float32),
         ],
         interpret=interpret,
-    )(nb_ids, offs_arr, rel, rel, shift, shift, v, v, m, inv_rho, inv_rho)
+        name="rcll_force",
+    )(offs_flat, rel_w, rel_w, shift, shift, v_w, v_w, m_w, inv_w, inv_w)
+    return drho.reshape(C, cap), acc

@@ -74,14 +74,14 @@ def tile_phys_disp_shifted(
     rel_j: Array,  # (d, cap)
     shift_i: Array,  # (d, cap) small-int cell shift (cell_now - cell_stale)
     shift_j: Array,  # (d, cap)
-    off_k: Array,  # (d,) f32
+    off_k,  # (d,) f32 offsets: an array or a list of scalars
     hc_phys: tuple,  # (d,) static physical cell edges
 ) -> tuple[list[Array], Array]:
     """Shift-anchored physics-tier pair displacement x_i - x_j per axis.
 
     The half-width force kernel streams the RAW fp16 relative coords
-    plus an int8 per-particle cell shift instead of a pre-shifted fp32
-    coordinate (half the coordinate bytes): the stale-binning re-anchor
+    plus an int16 per-particle cell shift instead of a pre-shifted fp32
+    coordinate: the stale-binning re-anchor
     ``rel' = rel + 2 (cell_now - cell_stale)`` happens here in fp32
     registers — the shift is an exact small integer and fp32 addition of
     an fp16 payload and a small integer is exact, so the decode is
@@ -117,3 +117,42 @@ def tile_pair_mask(
 ) -> Array:
     """Occupancy mask with the self-pair (same cell, same slot) removed."""
     return tile_occ_pair(occ_i, occ_j) & ~(is_self_cell & tile_self_mask(cap))
+
+
+def bits16_to_f32(bits: Array, dtype) -> Array:
+    """Decode raw 16-bit float words to f32 with integer ops.
+
+    ``bits`` is an int16/uint16 array holding the storage words of a
+    float16 or bfloat16 array (``dtype``). The result is bit-identical
+    to ``x.astype(jnp.float32)`` of the original array on every one of
+    the 65,536 patterns: signed zeros, subnormals (decoded as
+    ``mantissa * 2^-24``, an exact fp32 normal), infinities, and NaNs
+    (quieted, payload kept, as XLA's conversion does). Mosaic cannot
+    load float16 vectors, so the kernels stream the words and decode
+    them here in registers.
+    """
+    h = bits.astype(jnp.int32) & 0xFFFF
+    if jnp.dtype(dtype) == jnp.dtype(jnp.bfloat16):  # sphlint: disable=dtype-literal
+        return jax.lax.bitcast_convert_type(h << 16, jnp.float32)
+    if jnp.dtype(dtype) != jnp.dtype(jnp.float16):  # sphlint: disable=dtype-literal
+        raise ValueError(f"no 16-bit float decode for {dtype}")
+    sign = (h & 0x8000) << 16
+    exp = (h >> 10) & 0x1F
+    man = h & 0x3FF
+    normal = sign | ((exp + 112) << 23) | (man << 13)
+    quiet = jnp.where(man != 0, 0x00400000, 0)
+    special = sign | 0x7F800000 | (man << 13) | quiet
+    sub = jax.lax.bitcast_convert_type(
+        man.astype(jnp.float32) * (2.0 ** -24), jnp.int32
+    ) | sign
+    out = jnp.where(exp == 0, sub, jnp.where(exp == 31, special, normal))
+    return jax.lax.bitcast_convert_type(out, jnp.float32)
+
+
+def decode_f32(x: Array, dtype) -> Array:
+    """Kernel-side load: 16-bit words of ``dtype`` -> f32, else astype."""
+    if x.dtype in (jnp.int16, jnp.uint16) and jnp.dtype(dtype).kind == "f":
+        return bits16_to_f32(x, dtype)
+    if x.dtype == jnp.int16:
+        return x.astype(jnp.int32).astype(jnp.float32)
+    return x.astype(jnp.float32)

@@ -39,6 +39,7 @@ from repro.core import cases as cases_lib
 from repro.core import recovery
 from repro.core.api import Simulation
 from repro.core.precision import PrecisionPolicy
+from repro.runtime import compile_cache
 
 
 def _case_overrides(args) -> dict:
@@ -346,7 +347,7 @@ def cmd_serve(args) -> int:
             drain_timeout_s=args.drain_timeout,
             chaos=args.chaos,
         )
-        mode = "multi-process"
+        mode = f"multi-process devices={srv.chips}x{srv.platform}"
     # SIGTERM/SIGINT -> graceful drain: stop admitting, checkpoint
     # in-flight lanes, answer RETRY_AFTER, exit 0
     for sig in (signal.SIGTERM, signal.SIGINT):
@@ -530,8 +531,8 @@ def main(argv=None) -> int:
                     help="run engines in the server process (legacy "
                     "mode: no crash containment, no worker restarts)")
     vp.add_argument("--max-restarts", type=int, default=3,
-                    help="worker restarts per shape bucket before its "
-                    "requests get RETRY_AFTER with resume tokens "
+                    help="restarts of one device's engine worker before "
+                    "its requests get RETRY_AFTER with resume tokens "
                     "(default 3)")
     vp.add_argument("--hang-timeout", type=float, default=600.0,
                     help="seconds without block progress before a "
@@ -590,6 +591,7 @@ def main(argv=None) -> int:
     tp.set_defaults(fn=cmd_lint)
 
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if getattr(args, "fn", None) is cmd_request and not (
             args.case or args.resume_token):
         qp.error("request wants a case or --resume-token")

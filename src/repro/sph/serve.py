@@ -172,9 +172,8 @@ def request_key(req: dict) -> str:
 
 
 def worker_tag(req: dict) -> str:
-    """Filesystem-safe name for the engine worker owning a request's
-    shape bucket (stable across frontend restarts: resume tokens are
-    located by scanning ``workers/<tag>/lanes/<token>``)."""
+    """Filesystem-safe, human-readable name of a request's shape bucket
+    (stats and logs of the multi-process frontend)."""
     digest = hashlib.sha1(request_key(req).encode()).hexdigest()[:10]
     return f"{req['case']}-{digest}"
 
@@ -249,7 +248,7 @@ class _Pending:
     # multi-process routing state (FrontendServer only)
     rid: str | None = None
     token: str | None = None
-    wkey: str | None = None
+    chip: int | None = None
     steps: int = 0
     recovering: bool = False
     recovered: bool = False
@@ -294,8 +293,8 @@ class ServerBase:
     scheduling round (``_tick``), graceful shutdown (``_drain``), and
     the monitoring hooks (``_live_steps`` / ``_extra_stats``):
     :class:`SimServer` runs the engines in-process; the multi-process
-    :class:`repro.sph.supervisor.FrontendServer` routes to per-bucket
-    engine-worker processes.
+    :class:`repro.sph.supervisor.FrontendServer` routes to one
+    engine-worker process per device.
     """
 
     def __init__(

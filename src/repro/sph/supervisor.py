@@ -2,14 +2,19 @@
 
 :class:`FrontendServer` is the process clients connect to. It owns the
 client listener, frame validation, and the bounded admission queue
-(all inherited from :class:`repro.sph.serve.ServerBase`) — but no JAX
-compute. Each shape bucket (normalized case+resolution+overrides, see
-:func:`repro.sph.serve.request_key`) runs in its OWN engine-worker
-process (:mod:`repro.sph.worker`), spawned on demand, connected back
-over a localhost IPC socket speaking the same length-prefixed frame
-protocol. A native crash in one bucket (XLA segfault, OOM kill,
-runaway compile) kills one worker process; the frontend and every
-sibling bucket keep streaming, bit-identical to solo runs.
+(all inherited from :class:`repro.sph.serve.ServerBase`) — but it
+never opens a JAX backend: an accelerator chip belongs to one process,
+and the workers need it. The host's device count comes from a
+short-lived probe process (:func:`probe_devices`), and the pool holds
+ONE engine-worker process (:mod:`repro.sph.worker`) per device, spawned
+on demand and connected back over a localhost IPC socket speaking the
+same length-prefixed frame protocol. Shape buckets (normalized
+case+resolution+overrides, see :func:`repro.sph.serve.request_key`) are
+spread over the pool, least-loaded device first; a worker hosts one
+``LaneEngine`` per bucket it owns. On a multi-chip TPU host each worker
+is pinned to its own chip, so a native crash (XLA segfault, OOM kill,
+runaway compile) takes down one chip's buckets; the frontend and every
+bucket on the other chips keep streaming, bit-identical to solo runs.
 
 The supervisor (part of the frontend's engine loop) detects worker
 death three ways:
@@ -25,8 +30,9 @@ death three ways:
      least one block of progress, so a long first compile is never
      mistaken for a hang.
 
-On death the worker is restarted with capped exponential backoff; the
-restarted process reclaims the dead pid's lockfiles (quietly — one
+On death the supervisor reaps the process (so its chip is free) and
+restarts it with capped exponential backoff; the restarted process
+reclaims the dead pid's lockfiles (quietly — one
 summary line, not one warning per lane) and every in-flight request is
 re-admitted from its last per-lane block checkpoint (written
 continuously, every healthy block — recovery loses at most
@@ -76,14 +82,68 @@ log = logging.getLogger("repro.serve")
 CHAOS_MODES = ("kill", "hang", "oom-sim")
 
 
-class WorkerHandle:
-    """Supervisor-side state for one engine-worker process."""
+def _child_env() -> dict:
+    """The environment of a child process: ours, plus this checkout's
+    ``src`` on PYTHONPATH."""
+    env = dict(os.environ)
+    src = os.path.dirname(os.path.dirname(os.path.abspath(repro.__file__)))
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    return env
 
-    def __init__(self, wid: int, wkey: str, tag: str, wdir: str):
+
+_PROBE = """
+import os
+import jax
+from jax._src import hardware_utils
+# TPU hardware on the host: open the TPU or fail, never fall back
+if (not os.environ.get("JAX_PLATFORMS")
+        and hardware_utils.num_available_tpu_chips_and_device_id()[0]):
+    jax.config.update("jax_platforms", "tpu")
+d = jax.devices()
+print(len(d), d[0].platform)
+"""
+
+
+def probe_devices(timeout_s: float = 300.0) -> tuple[int, str]:
+    """(device count, platform) of this host, asked of a child process
+    that exits before any worker starts — the frontend itself never
+    initializes a JAX backend. A host with TPU chips must open them:
+    unless ``JAX_PLATFORMS`` says otherwise, the probe asks for the TPU
+    alone, so a chip that cannot be opened (still held by another
+    process, say) fails the probe instead of reporting the CPU. A probe
+    that fails raises: the service does not fall back to another
+    platform."""
+    out = subprocess.run(
+        [sys.executable, "-c", _PROBE], env=_child_env(),
+        capture_output=True, text=True, timeout=timeout_s)
+    if out.returncode != 0:
+        raise RuntimeError(
+            f"device probe failed (rc={out.returncode}): "
+            f"{out.stderr[-2000:]}")
+    count, platform = out.stdout.strip().splitlines()[-1].split()
+    return int(count), platform
+
+
+def pin_env(chip: int) -> dict:
+    """Environment that restricts a worker's TPU runtime to one chip of
+    a multi-chip host (one single-chip process per chip)."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(8476 + chip),
+    }
+
+
+class WorkerHandle:
+    """Supervisor-side state for one engine-worker process (one chip)."""
+
+    def __init__(self, wid: int, chip: int, wdir: str):
         self.wid = wid
-        self.wkey = wkey
-        self.tag = tag
+        self.chip = chip
+        self.tag = f"chip{chip}"
         self.dir = wdir
+        self.buckets: dict[str, str] = {}  # request_key -> worker_tag
         self.secret: str | None = None
         self.proc: subprocess.Popen | None = None
         self.conn: _Conn | None = None
@@ -98,6 +158,8 @@ class WorkerHandle:
         self.progress_since_spawn = False
         self.eof = False
         self.drained_steps: dict[str, int] | None = None
+        self.platform: str | None = None  # as the worker's hello says
+        self.kind: str | None = None
         self.assigned: dict[str, _Pending] = {}  # rid -> request
 
     @property
@@ -134,8 +196,10 @@ class FrontendServer(ServerBase):
         spawn_timeout_s: float = 120.0,
         worker_hb_timeout_s: float = 10.0,
         chaos: str | None = None,
+        devices: tuple[int, str] | None = None,
     ):
         self.policy = policy or recovery.GuardPolicy()
+        self.chips, self.platform = devices or probe_devices()
         self.slots = int(slots)
         self.max_restarts = int(max_restarts)
         self.hang_timeout_s = float(hang_timeout_s)
@@ -150,7 +214,8 @@ class FrontendServer(ServerBase):
         self.chaos = chaos
         self.chaos_fired_t: float | None = None
         self.last_recovery_s: float | None = None
-        self.workers: dict[str, WorkerHandle] = {}  # wkey -> handle
+        self.workers: dict[int, WorkerHandle] = {}  # chip -> handle
+        self.bucket_chip: dict[str, int] = {}  # request_key -> chip
         self.inflight: dict[str, _Pending] = {}     # rid -> request
         self.worker_restarts = 0
         self.recovered_lanes = 0
@@ -177,15 +242,15 @@ class FrontendServer(ServerBase):
         threading.Thread(target=self._ipc_accept_loop,
                          daemon=True).start()
         log.info("serve: frontend on %s:%d (ipc=%d slots=%d queue=%d "
-                 "block=%d max_restarts=%d%s)", self.host, self.port,
-                 self.ipc_port, self.slots, self.queue_cap,
-                 self.policy.block, self.max_restarts,
+                 "block=%d max_restarts=%d devices=%d x %s%s)",
+                 self.host, self.port, self.ipc_port, self.slots,
+                 self.queue_cap, self.policy.block, self.max_restarts,
+                 self.chips, self.platform,
                  f" chaos={chaos}" if chaos else "")
 
     def _has_resumables(self) -> bool:
         return (os.path.isdir(os.path.join(self.ckdir, "drain"))
-                or bool(glob.glob(os.path.join(
-                    self.ckdir, "workers", "*", "lanes", "*"))))
+                or bool(glob.glob(os.path.join(self._lanes_root(), "*"))))
 
     # ---- monitoring -----------------------------------------------------
     def _live_steps(self) -> list[int]:
@@ -194,16 +259,19 @@ class FrontendServer(ServerBase):
     def _extra_stats(self) -> dict:
         return {
             "live": len(self.inflight),
-            "buckets": len(self.workers),
+            "buckets": len(self.bucket_chip),
+            "chips": self.chips,
             "worker_restarts": self.worker_restarts,
             "recovered_lanes": self.recovered_lanes,
             "chaos": self.chaos,
             "chaos_fired": self.chaos_fired_t is not None,
             "recovery_s": self.last_recovery_s,
             "workers": [
-                {"wid": h.wid, "tag": h.tag, "pid": h.pid,
+                {"wid": h.wid, "tag": h.tag, "chip": h.chip, "pid": h.pid,
+                 "platform": h.platform, "kind": h.kind,
                  "state": h.state, "restarts": h.restarts,
-                 "blocks": h.blocks, "assigned": len(h.assigned)}
+                 "blocks": h.blocks, "assigned": len(h.assigned),
+                 "buckets": sorted(h.buckets.values())}
                 for h in list(self.workers.values())],
         }
 
@@ -262,20 +330,37 @@ class FrontendServer(ServerBase):
         return out
 
     # ---- worker lifecycle ----------------------------------------------
-    def _workers_root(self) -> str:
-        return os.path.join(self.ckdir, "workers")
+    def _lanes_root(self) -> str:
+        # shared by the pool: a token's lane checkpoint outlives the
+        # worker (and the chip) that wrote it
+        return os.path.join(self.ckdir, "lanes")
+
+    def _chip_for(self, wkey: str) -> int:
+        """The bucket's chip: sticky once chosen, else the chip owning
+        the fewest buckets (lowest index on ties)."""
+        chip = self.bucket_chip.get(wkey)
+        if chip is None:
+            load = [0] * self.chips
+            for c in self.bucket_chip.values():
+                load[c] += 1
+            chip = load.index(min(load))
+            self.bucket_chip[wkey] = chip
+        return chip
 
     def _ensure_worker(self, wkey: str, tag: str) -> WorkerHandle:
-        h = self.workers.get(wkey)
+        chip = self._chip_for(wkey)
+        h = self.workers.get(chip)
         if h is None:
-            wdir = os.path.join(self._workers_root(), tag)
-            h = WorkerHandle(self._next_wid, wkey, tag, wdir)
+            wdir = os.path.join(self.ckdir, "workers", f"chip{chip}")
+            h = WorkerHandle(self._next_wid, chip, wdir)
             self._next_wid += 1
-            self.workers[wkey] = h
+            self.workers[chip] = h
             self._spawn(h)
+        h.buckets[wkey] = tag
         return h
 
     def _spawn(self, h: WorkerHandle):
+        self._reap(h)
         h.secret = secrets.token_hex(16)
         with self.cond:
             self._by_secret[h.secret] = h
@@ -288,13 +373,15 @@ class FrontendServer(ServerBase):
         cmd = [sys.executable, "-m", "repro.sph.worker",
                "--connect", str(self.ipc_port), "--secret", h.secret,
                "--wid", str(h.wid), "--dir", h.dir,
+               "--lanes", self._lanes_root(), "--chip", str(h.chip),
                "--slots", str(self.slots),
                "--block", str(self.policy.block),
                "--save-every", str(self.save_every)]
-        env = dict(os.environ)
-        src = os.path.dirname(os.path.dirname(
-            os.path.abspath(repro.__file__)))
-        env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        env = _child_env()
+        # the worker opens the probed platform or fails: no fallback
+        env["JAX_PLATFORMS"] = self.platform
+        if self.platform == "tpu" and self.chips > 1:
+            env.update(pin_env(h.chip))
         h.proc = subprocess.Popen(cmd, env=env)
         log.info("serve: spawned worker w%d pid=%d for %s%s", h.wid,
                  h.proc.pid, h.tag,
@@ -311,7 +398,7 @@ class FrontendServer(ServerBase):
         """Resume token -> the saved request, located by scanning the
         worker lane dirs (stable across frontend restarts)."""
         hits = glob.glob(os.path.join(
-            self._workers_root(), "*", "lanes", token, "token.json"))
+            self._lanes_root(), token, "token.json"))
         for hit in hits:
             try:
                 with open(hit) as f:
@@ -348,7 +435,7 @@ class FrontendServer(ServerBase):
         if p.rid is None:
             p.rid = f"r{self._next_rid}"
             self._next_rid += 1
-        p.wkey = h.wkey
+        p.chip = h.chip
         self.inflight[p.rid] = p
         h.assigned[p.rid] = p
         self._send_admit(h, p)
@@ -360,9 +447,14 @@ class FrontendServer(ServerBase):
         kind = f.get("type")
         if kind == "hello":
             h.pid = int(f.get("pid") or 0)
+            h.platform, h.kind = f.get("platform"), f.get("kind")
+            if h.platform != self.platform:
+                self._on_death(h, f"opened platform {h.platform!r}, the "
+                               f"host's is {self.platform!r}")
+                return
             h.state = "ready"
-            log.info("serve: worker w%d (%s) ready, pid=%d", h.wid,
-                     h.tag, h.pid)
+            log.info("serve: worker w%d (%s) ready on %s (%s), pid=%d",
+                     h.wid, h.tag, h.platform, h.kind, h.pid)
             # crash recovery: re-admit everything it owed, from the
             # per-lane checkpoints its predecessor wrote
             for p in list(h.assigned.values()):
@@ -461,7 +553,7 @@ class FrontendServer(ServerBase):
     def _supervise(self):
         now = time.monotonic()
         self._maybe_fire_chaos(now)
-        for wkey, h in list(self.workers.items()):
+        for h in list(self.workers.values()):
             if h.state == "backoff":
                 if now >= h.restart_at:
                     self._spawn(h)
@@ -501,11 +593,22 @@ class FrontendServer(ServerBase):
             except OSError:
                 pass
 
+    def _reap(self, h: WorkerHandle):
+        """Kill (if still running) and wait for the worker process, so
+        the chip it held is free before anything respawns on it."""
+        if h.proc is None:
+            return
+        self._kill(h)
+        try:
+            h.proc.wait(timeout=30)
+        except subprocess.TimeoutExpired:
+            log.error("serve: worker w%d pid=%d not reaped after SIGKILL",
+                      h.wid, h.proc.pid)
+
     def _on_death(self, h: WorkerHandle, why: str):
         h.restarts += 1
         self.worker_restarts += 1
-        if h.alive_proc:  # EOF with the process somehow lingering
-            self._kill(h)
+        self._reap(h)
         log.warning("serve: worker w%d (%s) died: %s — %d in-flight, "
                     "restart %d/%d", h.wid, h.tag, why, len(h.assigned),
                     h.restarts, self.max_restarts)
@@ -531,7 +634,7 @@ class FrontendServer(ServerBase):
             # drop the handle: lane checkpoints stay on disk, and a
             # later request (or token resubmission) starts a fresh
             # worker with a clean restart budget
-            del self.workers[h.wkey]
+            del self.workers[h.chip]
             return
         delay = min(self.backoff_cap_s,
                     self.backoff_base_s * 2 ** (h.restarts - 1))
@@ -615,7 +718,7 @@ class FrontendServer(ServerBase):
                 p.reply({"type": "timeout",
                          "deadline_s": p.req["deadline_s"],
                          "steps_done": p.steps})
-                h = self.workers.get(p.wkey)
+                h = self.workers.get(p.chip)
                 if h is not None:
                     self._retire(h, p, discard=True)
                 else:
@@ -650,7 +753,7 @@ class FrontendServer(ServerBase):
         # are already on disk (continuous per-block saves), with the
         # drain's final save on top where the worker answered in time
         for rid, p in list(self.inflight.items()):
-            h = self.workers.get(p.wkey)
+            h = self.workers.get(p.chip)
             steps = p.steps
             if h is not None and h.drained_steps is not None:
                 steps = h.drained_steps.get(rid, steps)

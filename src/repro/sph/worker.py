@@ -1,15 +1,20 @@
-"""Engine-worker process: one shape bucket's :class:`LaneEngine` behind
-a local IPC channel.
+"""Engine-worker process: one device's lane engines (a
+:class:`LaneEngine` per shape bucket routed to it) behind a local IPC
+channel.
 
 ``python -m repro.sph.worker`` is spawned by the multi-process frontend
 (:mod:`repro.sph.supervisor`), connects BACK to the frontend's IPC
 listener, authenticates with a one-shot secret, and then serves admit /
 retire / drain / chaos commands over the same length-prefixed frame
-protocol clients speak. The worker owns its own JAX runtime, its own
-checkpoint directory (``<root>/workers/<tag>/``) with the PR 7 ``.lock``
-exclusivity file, and its own :class:`HeartbeatWriter` — so a native
-crash (XLA segfault, OOM kill, runaway compile) takes down ONE shape
-bucket while the frontend and every sibling bucket keep streaming.
+protocol clients speak. The worker owns its own JAX runtime on its one
+device (``--chip``; on a multi-chip TPU host the frontend pins the
+process to that chip through its environment), its own directory
+(``<root>/workers/chip<i>/``) with the ``.lock`` exclusivity file, and
+its own :class:`HeartbeatWriter` — so a native crash (XLA segfault, OOM
+kill, runaway compile) takes down ONE device's buckets while the
+frontend and every other device keep streaming. Lane checkpoints live
+in the pool-wide ``<root>/lanes/<token>/``, so any worker can resume
+any token.
 
 Crash containment contract:
   * every live lane is checkpointed at every healthy block boundary
@@ -33,7 +38,9 @@ Crash containment contract:
     warning per resumed lane.
 
 Worker frames (worker -> frontend), all rid-tagged where relevant:
-  hello {wid, secret, pid}      authentication, sent once on connect
+  hello {wid, secret, pid, platform, kind}
+                                authentication + the device it opened,
+                                sent once on connect
   accepted {rid, lane, nsteps, steps_done, resumed}
   busy {rid}                    EngineFull/FaultBusy: frontend requeues
   obs / event / done / diverged / error   relayed to the client
@@ -59,6 +66,7 @@ import numpy as np
 
 from repro.checkpoint import manager as ckpt
 from repro.core import ensemble, health, recovery
+from repro.runtime import compile_cache
 from repro.runtime.fault_tolerance import HeartbeatWriter
 from repro.sph import serve
 
@@ -92,11 +100,12 @@ def _meta_template() -> dict:
 class EngineWorker:
     """The worker's engine loop: single thread owns every JAX call."""
 
-    def __init__(self, chan: serve._Conn, wdir: str, *, slots: int,
-                 policy: recovery.GuardPolicy, save_every: int = 1,
-                 hb_interval_s: float = 0.5):
+    def __init__(self, chan: serve._Conn, wdir: str, lanes_dir: str, *,
+                 slots: int, policy: recovery.GuardPolicy,
+                 save_every: int = 1, hb_interval_s: float = 0.5):
         self.chan = chan
         self.wdir = wdir
+        self.lanes_dir = lanes_dir
         self.slots = int(slots)
         self.policy = policy
         self.save_every = max(1, int(save_every))
@@ -112,7 +121,7 @@ class EngineWorker:
         self.lane_rid: dict[tuple, str] = {}  # (key, lane) -> rid
         self.blocks = 0
         os.makedirs(wdir, exist_ok=True)
-        # the worker-dir lock: one engine process per bucket directory
+        # the worker-dir lock: one engine process per device directory
         self.dirlock = ckpt.CheckpointManager(wdir, keep=0,
                                               quiet_reclaim=True)
         self.reclaimed = ([self.dirlock.reclaimed_from]
@@ -217,7 +226,7 @@ class EngineWorker:
         return -(-int(nsteps) // block) * block
 
     def _lane_dir(self, token: str) -> str:
-        return os.path.join(self.wdir, "lanes", token)
+        return os.path.join(self.lanes_dir, token)
 
     def _engine_for(self, cfg, n: int) -> tuple:
         key = (ensemble.member_config(cfg, self.policy), n)
@@ -235,6 +244,9 @@ class EngineWorker:
             n = int(state.xn.shape[0])
             key = self._engine_for(cfg, n)
             engine = self.engines[key]
+            if not engine.free_lanes:
+                # before the lane template: a full bucket answers at once
+                raise ensemble.EngineFull(f"all {engine.slots} lanes busy")
             nsteps = self._blocks_of(req.get("nsteps") or default_nsteps)
             fault = None
             inject = req.get("inject")
@@ -453,6 +465,20 @@ class EngineWorker:
                             "detail": f"{type(e).__name__}: {e}"})
 
 
+def _use_device(chip: int):
+    """Run this worker's programs on device ``chip`` of the host and
+    return it. A worker pinned by its environment sees one device, its
+    own."""
+    import jax
+
+    devices = jax.devices()
+    dev = devices[chip] if len(devices) > 1 else devices[0]
+    if len(devices) > 1:
+        jax.config.update("jax_default_device", dev)
+    log.info("worker: device %s (%s)", dev, dev.device_kind)
+    return dev
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="repro.sph.worker")
     ap.add_argument("--connect", type=int, required=True,
@@ -460,6 +486,10 @@ def main(argv=None) -> int:
     ap.add_argument("--secret", required=True)
     ap.add_argument("--wid", type=int, required=True)
     ap.add_argument("--dir", required=True, help="worker state dir")
+    ap.add_argument("--lanes", required=True,
+                    help="lane checkpoint root shared by the pool")
+    ap.add_argument("--chip", type=int, default=0,
+                    help="index of this worker's device on the host")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--block", type=int, default=32)
     ap.add_argument("--save-every", type=int, default=1)
@@ -468,6 +498,9 @@ def main(argv=None) -> int:
         level=logging.INFO,
         format=f"%(asctime)s w{args.wid} %(name)s %(levelname)s "
                "%(message)s")
+    # the device first: "hello" (-> ready) means this process holds it
+    compile_cache.enable()
+    dev = _use_device(args.chip)
     sock = None
     for attempt in range(10):
         try:
@@ -482,10 +515,11 @@ def main(argv=None) -> int:
     sock.settimeout(None)  # connect timeout must not poison blocking reads
     chan = serve._Conn(sock)
     chan.send({"type": "hello", "wid": args.wid, "secret": args.secret,
-               "pid": os.getpid()})
+               "pid": os.getpid(), "platform": dev.platform,
+               "kind": dev.device_kind})
     policy = recovery.GuardPolicy(block=args.block, snapshot_every=1)
-    w = EngineWorker(chan, args.dir, slots=args.slots, policy=policy,
-                     save_every=args.save_every)
+    w = EngineWorker(chan, args.dir, args.lanes, slots=args.slots,
+                     policy=policy, save_every=args.save_every)
     return w.run()
 
 

@@ -33,8 +33,13 @@ class ServerProc:
 
     def __init__(self, *extra_args: str, checkpoint: str,
                  block: int = 8, slots: int = 2, queue: int = 8,
-                 env: dict | None = None, banner_timeout: float = 120.0):
+                 env: dict | None = None, banner_timeout: float = 120.0,
+                 devices: int | None = None):
         env = dict(env or os.environ)
+        if devices is not None:
+            # a CPU host with ``devices`` devices: one worker each
+            env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_"
+                                f"host_platform_device_count={devices}")
         env.setdefault("PYTHONPATH", os.path.join(
             os.path.dirname(__file__), "..", "src"))
         self.proc = subprocess.Popen(
@@ -83,7 +88,7 @@ class ServerProc:
         raise AssertionError(f"server never reached {what}; last: {st}")
 
     def worker_pids(self) -> dict[str, int]:
-        """tag -> pid of every live worker (via the stats op)."""
+        """tag (``chip<i>``) -> pid of every live worker (stats op)."""
         return {w["tag"]: w["pid"] for w in self.stats()["workers"]
                 if w["pid"] is not None and w["state"] == "ready"}
 

@@ -146,8 +146,8 @@ def test_fused_gradient_matches_two_pass():
 # --------------------------------------------------------------------------
 @pytest.mark.parametrize("n,dim,seed", [(500, 2, 0), (300, 3, 1)])
 def test_cell_pack_kernel_matches_ref(n, dim, seed):
-    from repro.kernels import cell_pack
-
+    """The windowed slab pack equals the per-field id-table gather
+    (``cells.to_cell_major``) plus the sentinel row."""
     rng = np.random.default_rng(seed)
     ds = (1.0 / n) ** (1.0 / dim)
     dom = (D.unit_square(h=1.2 * ds) if dim == 2
@@ -157,20 +157,45 @@ def test_cell_pack_kernel_matches_ref(n, dim, seed):
     cap = cells.default_capacity(dom, n, safety=6.0)
     ps = rcll.pack_state(dom, st, cap)
     b = ps.packing.binning
-    starts = cells.exclusive_cumsum(b.counts)
     rows16 = jax.lax.bitcast_convert_type(ps.rc.rel, jnp.uint16)
     rows32 = jnp.asarray(rng.normal(size=(n, 2)), jnp.float32)
     fill32 = jnp.asarray([1.0, 0.0], jnp.float32)
-    out_k = cell_pack.cell_tables(
-        rows16, rows32, starts, b.counts, fill32, cap=cap, interpret=True
-    )
-    out_r = cell_pack.cell_tables_ref(
-        rows16, rows32, starts, b.counts, fill32, cap=cap
+    out_k = ops.cell_tables(rows16, rows32, b.counts, fill32, cap=cap)
+    t16 = cells.to_cell_major(b, rows16).transpose(0, 2, 1)
+    t32 = jnp.stack([
+        cells.to_cell_major(b, rows32[:, f], fill=float(fill32[f]))
+        for f in range(2)], axis=1)
+    out_r = (
+        jnp.concatenate([t16, jnp.zeros((1,) + t16.shape[1:], t16.dtype)]),
+        jnp.concatenate([t32, jnp.broadcast_to(
+            fill32[None, :, None], (1, 2, cap))]),
     )
     for a, c in zip(out_k, out_r):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
-    # the emitted id table IS the counting-sort packed table (+ sentinel)
+
+
+# --------------------------------------------------------------------------
+# In-kernel 16-bit float decode (the force kernel's fp16 loads)
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("dtype", [jnp.float16, jnp.bfloat16])
+def test_bits16_decode_bit_exact_all_patterns(dtype):
+    """Every one of the 65,536 words decodes inside a Pallas kernel to
+    exactly the bits of ``astype(float32)``: signed zeros, subnormals,
+    infinities and NaNs included."""
+    from jax.experimental import pallas as pl
+
+    from repro.kernels import tiling
+
+    words = np.arange(65536, dtype=np.uint16).view(np.int16).reshape(
+        512, 128)
+
+    def kernel(w_ref, o_ref):
+        o_ref[...] = tiling.bits16_to_f32(w_ref[...], dtype)
+
+    got = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((512, 128), jnp.float32),
+        interpret=True,
+    )(jnp.asarray(words))
+    want = jnp.asarray(words).view(dtype).astype(jnp.float32)
     np.testing.assert_array_equal(
-        np.asarray(out_k[2][:-1]), np.asarray(b.table)
-    )
-    assert np.all(np.asarray(out_k[2][-1]) == -1)
+        np.asarray(got).view(np.uint32), np.asarray(want).view(np.uint32))

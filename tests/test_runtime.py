@@ -126,3 +126,56 @@ def test_plan_elastic_mesh():
     data = sm["mesh_shape"][0]
     assert data * 16 <= 248
     assert 256 % data == 0
+
+
+# --------------------------------------------------------------------------
+# Persistent compilation cache location (runtime/compile_cache.py)
+# --------------------------------------------------------------------------
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CACHE_PROBE = (
+    "import jax, os\n"
+    "from repro.runtime import compile_cache\n"
+    "d = compile_cache.enable()\n"
+    "print(d, jax.config.jax_compilation_cache_dir,"
+    " os.environ['JAX_COMPILATION_CACHE_DIR'])\n"
+)
+
+
+def _cache_probe(cwd, env):
+    import subprocess
+    import sys
+
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE], cwd=cwd, env=env,
+        capture_output=True, text=True, timeout=120, check=True)
+    return out.stdout.split()
+
+
+def test_compile_cache_honours_env_var(tmp_path):
+    from repro.runtime import compile_cache
+
+    mine = str(tmp_path / "elsewhere")
+    assert compile_cache.cache_dir({"JAX_COMPILATION_CACHE_DIR": mine}) \
+        == mine
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=mine)
+    env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    # the helper picks nothing of its own: every view is the env's dir
+    assert _cache_probe(str(tmp_path), env) == [mine] * 3
+
+
+def test_compile_cache_fixed_checkout_path(tmp_path):
+    from repro.runtime import compile_cache
+
+    want = os.path.join(_ROOT, ".jax_cache")
+    assert os.path.realpath(compile_cache.cache_dir({})) == \
+        os.path.realpath(want)
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    # two processes (new pid, later clock), two working directories:
+    # one directory, the checkout's
+    runs = [_cache_probe(str(tmp_path), env), _cache_probe("/", env)]
+    for run in runs:
+        assert [os.path.realpath(p) for p in run] == \
+            [os.path.realpath(want)] * 3
+

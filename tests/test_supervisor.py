@@ -7,9 +7,12 @@ The contract under test:
     request's outcome: the supervisor restarts the worker, the lane
     resumes from its last block checkpoint, and the final state is
     BIT-IDENTICAL to an uninterrupted solo run;
-  * a sibling shape bucket streams through the whole episode untouched
-    (no recovering event, bit-identical state) and the frontend process
-    never exits;
+  * a sibling shape bucket on another device's worker streams through
+    the whole episode untouched (no recovering event, bit-identical
+    state) and the frontend process never exits;
+  * the pool holds one worker per device: buckets spread over the
+    devices, never more workers than devices, two buckets on a
+    one-device host share one worker;
   * the restarted worker reclaims its dead predecessor's lockfiles
     QUIETLY — one summary line, no per-lane warning spam;
   * ``--max-restarts`` exhaustion answers RETRY_AFTER with a resume
@@ -33,6 +36,8 @@ from repro.core.api import Simulation
 from repro.core.cases import resolve_ds
 from repro.sph import client
 from repro.sph.serve import recv_frame, request_key, send_frame, worker_tag
+from repro.sph import supervisor
+from repro.sph.supervisor import FrontendServer
 
 BLOCK = 8
 POLICY = recovery.GuardPolicy(block=BLOCK, snapshot_every=1)
@@ -65,6 +70,120 @@ class TestRouting:
         assert request_key(a) == request_key(c)  # nsteps/flags don't
         assert worker_tag(a) != worker_tag(b)
         assert worker_tag(a).startswith("taylor_green-")
+
+
+class _PoolProbe(FrontendServer):
+    """A frontend whose spawns are recorded, not run (no worker
+    processes, no JAX) — the routing half of the pool under test."""
+
+    def _spawn(self, h):
+        self.spawned.append(h.chip)
+
+
+def _pool(tmp_path, chips):
+    srv = _PoolProbe(port=0, checkpoint_dir=str(tmp_path),
+                     devices=(chips, "tpu"))
+    srv.spawned = []
+    return srv
+
+
+def _close(srv):
+    srv.stopped.set()
+    srv.ipc_sock.close()
+    srv.lsock.close()
+
+
+def _req(case, n):
+    return {"case": case, "n": n}
+
+
+class TestWorkerPool:
+    def test_one_chip_two_buckets_one_worker(self, tmp_path):
+        srv = _pool(tmp_path, 1)
+        try:
+            for r in (_req("taylor_green", 100), _req("dam_break", 100),
+                      _req("taylor_green", 100)):
+                srv._ensure_worker(request_key(r), worker_tag(r))
+            assert srv.spawned == [0]
+            assert list(srv.workers) == [0]
+            assert len(srv.workers[0].buckets) == 2
+            st = srv._extra_stats()
+            assert st["chips"] == 1 and st["buckets"] == 2
+            assert len(st["workers"]) == 1
+        finally:
+            _close(srv)
+
+    def test_buckets_spread_never_more_workers_than_chips(self, tmp_path):
+        srv = _pool(tmp_path, 4)
+        try:
+            reqs = [_req("taylor_green", n) for n in (100, 120, 140)] + [
+                _req("dam_break", n) for n in (100, 120, 140)]
+            chips = [srv._ensure_worker(request_key(r), worker_tag(r)).chip
+                     for r in reqs]
+            # least-loaded first: four buckets fill four chips, then wrap
+            assert chips == [0, 1, 2, 3, 0, 1]
+            assert sorted(srv.spawned) == [0, 1, 2, 3]
+            assert len(srv.workers) == 4
+            # a bucket is sticky to its chip
+            assert srv._ensure_worker(
+                request_key(reqs[2]), worker_tag(reqs[2])).chip == 2
+        finally:
+            _close(srv)
+
+    @pytest.mark.parametrize("chips", [1, 4])
+    def test_worker_pinned_to_its_chip_on_multichip_tpu(
+            self, tmp_path, monkeypatch, chips):
+        spawned = []
+
+        class FakeProc:
+            pid = 1
+
+            def __init__(self, cmd, env):
+                spawned.append((cmd, env))
+
+            def poll(self):
+                return None
+
+        monkeypatch.setattr(supervisor.subprocess, "Popen", FakeProc)
+        monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+        srv = FrontendServer(port=0, checkpoint_dir=str(tmp_path),
+                             devices=(chips, "tpu"))
+        try:
+            for r in (_req("taylor_green", 100), _req("dam_break", 100)):
+                srv._ensure_worker(request_key(r), worker_tag(r))
+        finally:
+            _close(srv)
+        assert len(spawned) == min(chips, 2)
+        for chip, (cmd, env) in enumerate(spawned):
+            assert cmd[cmd.index("--chip") + 1] == str(chip)
+            assert env["JAX_PLATFORMS"] == "tpu"  # no fallback to the CPU
+            if chips == 1:  # a one-chip host: nothing to pin
+                assert "TPU_VISIBLE_CHIPS" not in env
+            else:
+                assert {k: env[k] for k in supervisor.pin_env(chip)} == \
+                    supervisor.pin_env(chip)
+
+
+    @pytest.mark.parametrize("platform", ["tpu", "cpu"])
+    def test_worker_on_another_platform_is_refused(self, tmp_path,
+                                                   platform):
+        """A worker whose device is not the probed platform (a TPU it
+        could not open, say) dies and is respawned; it never serves."""
+        srv = _pool(tmp_path, 1)
+        try:
+            r = _req("taylor_green", 100)
+            h = srv._ensure_worker(request_key(r), worker_tag(r))
+            srv._handle_worker_frame(h, {
+                "type": "hello", "pid": 1, "platform": platform,
+                "kind": f"{platform} device"})
+            (w,) = srv._extra_stats()["workers"]
+            assert w["platform"] == platform
+            if platform == "tpu":
+                assert h.state == "ready" and srv.worker_restarts == 0
+            else:
+                assert h.state == "backoff" and srv.worker_restarts == 1
+        finally:
+            _close(srv)
 
 
 class _FakeServer:
@@ -159,11 +278,13 @@ class TestSupervisorE2E:
         (the supervisor's deterministic chaos-kill — a real SIGKILL
         timed right after a committed block checkpoint); its request
         must finish bit-identical to an uninterrupted run, a request in
-        a DIFFERENT bucket must stream through undisturbed, and the
-        frontend must never exit."""
+        a DIFFERENT bucket, served by the other device's worker, must
+        stream through undisturbed, and the frontend must never exit.
+        The host is a two-device CPU (XLA's forced host device count),
+        so the pool holds two workers."""
         srv = chaos.ServerProc("--chaos", "kill",
                                checkpoint=str(tmp_path / "ck"),
-                               block=BLOCK)
+                               block=BLOCK, devices=2)
         results = {}
 
         def fire(rid, req):
@@ -208,6 +329,9 @@ class TestSupervisorE2E:
         assert obs_a == set(range(BLOCK, 160, BLOCK))
 
         st = srv.stats()
+        # one worker per device, each bucket on its own
+        assert st["chips"] == 2 and len(st["workers"]) == 2
+        assert sorted(len(w["buckets"]) for w in st["workers"]) == [1, 1]
         assert st["worker_restarts"] >= 1
         assert st["recovered_lanes"] >= 1
         assert st["recovery_s"] is not None and st["recovery_s"] > 0

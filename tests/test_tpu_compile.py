@@ -1,0 +1,129 @@
+"""The main path's kernels compile for a TPU v5e at production shapes.
+
+Ahead-of-time compiles against a described ``v5e:2x2`` topology (no
+chip attached) with ``interpret=False``: the TPU compiler refuses here
+what interpret mode cannot see — block shapes off the (8, 128) tiling,
+vector loads of types Mosaic has no layout for, scalar-prefetch tables
+larger than SMEM — and ``memory_analysis`` shows whether the program
+fits the chip's 16 GiB. Shapes are those of ``cases.build_case(...)``
+at the stated particle counts.
+
+The topology is described inside a module fixture, never at import:
+only one process may load the TPU library, and every test worker
+imports this file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import cases
+from repro.kernels import ops, rcll_force
+
+HBM_BYTES = 16 * 2**30  # TPU v5e
+
+# (case, target n, particles N, grid, cap) from build_case(...).build()
+SHAPES = {
+    "dam_break-1M": ("dam_break", 1_000_000, 1_019_228, (1182, 768), 18),
+    "dam_break-64k": ("dam_break", 64_000, 69_038, (301, 196), 18),
+    "taylor_green-1M": ("taylor_green", 1_000_000, 1_000_000, (416, 416),
+                        20),
+}
+RECORDS = {"fp16": jnp.float16, "bf16": jnp.bfloat16, "fp32": jnp.float32}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means: cannot describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_compile_cache():
+    """Described-topology compiles cannot be read back without a chip:
+    keep them out of the persistent cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _fits(compiled) -> int:
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < HBM_BYTES, ma
+    return total
+
+
+def _case(key):
+    name, n, _, grid, cap = SHAPES[key]
+    case = cases.build_case(name, ds=cases.resolve_ds(name, n))
+    dom = case.domain()
+    assert tuple(dom.ncells) == grid, dom.ncells
+    return case, dom, cap
+
+
+@pytest.mark.parametrize("key,records", [
+    ("dam_break-1M", "fp16"), ("dam_break-1M", "bf16"),
+    ("dam_break-1M", "fp32"), ("dam_break-64k", "fp16"),
+    ("taylor_green-1M", "fp16"),
+])
+def test_force_kernel_compiles_for_v5e(one_chip, key, records):
+    case, dom, cap = _case(key)
+    d = dom.dim
+    c1 = dom.ncells_total + 1
+    rdt = RECORDS[records]
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = rcll_force.rcll_force.lower(
+        spec((c1, d, cap), jnp.float16),  # rel: fp16 storage coords
+        spec((c1, d, cap), jnp.int16),  # stale-cell shift
+        spec((c1, d, cap), rdt),  # v
+        spec((c1, cap), rdt),  # m
+        spec((c1, cap), jnp.float32),  # 1/rho
+        ncells=tuple(dom.ncells), periodic=tuple(dom.periodic),
+        hc_phys=tuple(dom.cell_sizes), h=dom.h, dim=d,
+        scheme=case.scheme(), interpret=False,
+    )
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("key", ["dam_break-1M", "dam_break-64k"])
+def test_cell_pack_compiles_for_v5e(one_chip, key):
+    _, n, N, grid, cap = SHAPES[key]
+    d = len(grid)
+    C = int(np.prod(grid))
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pack = jax.jit(functools.partial(ops.cell_tables, cap=cap))
+    compiled = pack.lower(
+        spec((N, 3 * d), jnp.uint16),  # [rel | shift | v] 16-bit slab
+        spec((N, 1), jnp.float32),  # [1/rho] fp32 slab
+        spec((C,), jnp.int32),
+        spec((1,), jnp.float32),
+    ).compile()
+    _fits(compiled)

@@ -61,9 +61,10 @@ HALF_DTYPES = ("float16", "bfloat16")
 # --------------------------------------------------------------------------
 def _sub_jaxprs(value):
     """Yield every Jaxpr nested in an eqn param value."""
-    import jax
+    # jax.extend is a submodule: import it (``hasattr(jax, "extend")``
+    # held only once something else had imported it)
+    from jax.extend import core
 
-    core = jax.extend.core if hasattr(jax, "extend") else jax.core
     ClosedJaxpr = core.ClosedJaxpr
     Jaxpr = core.Jaxpr
     if isinstance(value, ClosedJaxpr):
