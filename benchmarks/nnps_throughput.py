@@ -19,10 +19,8 @@ Reported per case:
     place, init/compile excluded);
   * physics-only ms/step (a scan of pure ``_physics_step``, no rebuild
     cond) vs the NNPS rebuild cost in ms and the observed rebuild
-    frequency — the paper's Table 6 style split;
-  * the analytic HBM bytes/step model for both paths and both record
-    layouts (``fused.estimate_hbm_bytes_per_step``): CPU wall times are
-    a proxy (see _util), the byte ratio is what transfers to TPU/GPU.
+    frequency — the paper's Table 6 style split. CPU wall times are a
+    proxy (see _util).
 
 Results are APPENDED to ``BENCH_nnps.json`` (the file holds a list of
 run records, oldest first) so the perf trajectory persists across PRs;
@@ -53,7 +51,7 @@ import jax
 import numpy as np
 
 from benchmarks._util import emit, time_fn
-from repro.core import cases, fused, solver
+from repro.core import cases, solver
 from repro.core.precision import PrecisionPolicy
 
 BENCH_PATH = "BENCH_nnps.json"
@@ -172,7 +170,6 @@ def run_case(
     rebuild_frequency = rebuilds / (timed_segments * nsteps)
     overflow = bool(carry.overflow)
 
-    k, d = max_neighbors, cfg.domain.dim
     row = {
         "case": case_name,
         "dynamic": dynamic,
@@ -182,7 +179,7 @@ def run_case(
         "records": records,
         "skin_frac_hc": skin_frac_hc,
         "skin": float(cfg.skin),
-        "max_neighbors": k,
+        "max_neighbors": max_neighbors,
         "nsteps": nsteps,
         # the donated-scan steps/sec INCLUDES every in-scan rebuild: in
         # --dynamic mode this IS the amortized throughput
@@ -193,12 +190,6 @@ def run_case(
         "rebuild_frequency": round(rebuild_frequency, 4),
         "rebuilds_per_100_steps": round(100.0 * rebuild_frequency, 1),
         "overflow": overflow,
-        "hbm_model_bytes_per_step_gather": fused.estimate_hbm_bytes_per_step(
-            n, k, d, fused=False
-        ),
-        "hbm_model_bytes_per_step_fused": fused.estimate_hbm_bytes_per_step(
-            n, k, d, fused=True, records=records
-        ),
     }
     if dynamic:
         # alias, emitted only where it means something (rebuilds fired
@@ -317,8 +308,6 @@ def main(
             layout_speedups[str(n_target)] = round(
                 h16["steps_per_sec"] / f32["steps_per_sec"], 3
             )
-    k, d = rows[0]["max_neighbors"], 2
-    n0 = rows[0]["n_particles"]
     record = {
         "label": "rebuild_round",
         "case": case_name,
@@ -329,17 +318,6 @@ def main(
         "cases": rows,
         "steps_per_sec_speedup_fused_vs_gather": speedups,
         "steps_per_sec_half_vs_fp32_records": layout_speedups,
-        "hbm_model_ratio_gather_over_fused": round(
-            rows[0]["hbm_model_bytes_per_step_gather"]
-            / fused.estimate_hbm_bytes_per_step(
-                n0, k, d, fused=True, records="fp16"
-            ), 2,
-        ),
-        "hbm_model_ratio_fp32_over_half_records": round(
-            fused.estimate_hbm_bytes_per_step(n0, k, d, records="fp32")
-            / fused.estimate_hbm_bytes_per_step(n0, k, d, records="fp16"),
-            2,
-        ),
     }
     return _finish(record, failed, append, out, speedups)
 

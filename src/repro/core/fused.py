@@ -496,45 +496,3 @@ def force_rhs(
         body, (idx, rec16[:n], inv32[:n]), pad_rows, n, chunk
     )
     return drho * m_scale, acc * m_scale  # undo the mass normalization
-
-
-def record_bytes_per_pair(d: int, records: str = "fp32") -> int:
-    """Record bytes gathered per neighbor pair under a record layout.
-
-    fp32: one (2d+3)-column fp32 row. Half-width: one (3d+1)-column
-    uint16 row plus the single fp32 rho gather (p/ρ² is recomputed
-    in-register from 1/rho — see ``sph.eos_tait_por2_inv``).
-    """
-    if jnp.dtype(dtype_of(records)).itemsize == 2:
-        return (3 * d + 1) * 2 + 4
-    return (2 * d + 3) * 4
-
-
-def estimate_hbm_bytes_per_step(
-    n: int, k: int, d: int, fused: bool = True, records: str = "fp32"
-) -> int:
-    """Back-of-envelope HBM pair-traffic model for one physics step.
-
-    Gather (reference) path materializes, per step: disp (N,K,d), r
-    (N,K), gw (N,K,d), dv (N,K,d), mj (N,K), plus per-term coefficient
-    arrays pij/x_dot_gw/rho_ij/coef (N,K) — ~(6d + 9) N·K fp32 write+read
-    round-trips — and performs ~6 scalar neighbor gathers.
-
-    Fused path, per step: ONE sanitized-id read per pair (int32 — the
-    sanitize itself, idx + mask read and idx_dummy write, happens once
-    per REBUILD since PR 2 and is amortized out of the per-step model,
-    which the PR 2 model overcounted), the record gather
-    (``record_bytes_per_pair`` — layout-dependent), and O(N)
-    per-particle traffic (record build write + self-row read + drho/acc
-    out); pair intermediates never leave cache.
-    """
-    nk = n * k
-    if fused:
-        ids = nk * 4  # sanitized idx read, one sweep
-        rec = record_bytes_per_pair(d, records)
-        gathers = nk * rec
-        per_particle = n * (2 * rec + (d + 1) * 4)
-        return ids + gathers + per_particle
-    round_trips = 2 * (6 * d + 9)  # write + read back of each pair array
-    gathers = nk * (2 * d + 3 + d) * 4  # rel/cell/v/m/rho/p scalar
-    return nk * round_trips * 4 + gathers

@@ -436,54 +436,64 @@ def _rebuild(cfg: SPHConfig, carry: PersistentCarry) -> PersistentCarry:
     happen - the fused kernel sees every in-support pair).
     """
     n = carry.order.shape[0]
-    ps = rcll.pack_state(
-        cfg.domain, carry.st.rc, cfg.cap(n), prev=carry.binning
-    )
-    perm = ps.packing.order  # current-packed -> new-packed
-    st, order = _permute_state_fused(carry.st, perm, ps.rc, carry.order)
-    cell_over = ps.packing.binning.overflow > 0
-    overflow = carry.overflow | cell_over
-    flags = health.fold_flag(carry.flags, cell_over, health.CELL_OVERFLOW)
-    binning = ps.packing.binning
-    m_table = carry.m_table
-    if cfg.resolved_backend == "pallas":
-        from repro.kernels import ops  # deferred: core stays kernel-free
+    with jax.named_scope("sph.rebuild"):
+        with jax.named_scope("sph.rebuild.pack"):
+            ps = rcll.pack_state(
+                cfg.domain, carry.st.rc, cfg.cap(n), prev=carry.binning
+            )
+        perm = ps.packing.order  # current-packed -> new-packed
+        with jax.named_scope("sph.rebuild.permute"):
+            st, order = _permute_state_fused(
+                carry.st, perm, ps.rc, carry.order
+            )
+        cell_over = ps.packing.binning.overflow > 0
+        overflow = carry.overflow | cell_over
+        flags = health.fold_flag(carry.flags, cell_over,
+                                 health.CELL_OVERFLOW)
+        binning = ps.packing.binning
+        m_table = carry.m_table
+        if cfg.resolved_backend == "pallas":
+            from repro.kernels import ops  # deferred: core stays kernel-free
 
-        nl = _empty_neighbor_list(n)
-        idx_dummy = None
-        m_table = ops.mass_table(
-            binning, st.fluid.m, cfg.policy.records_dtype, carry.m_scale
+            nl = _empty_neighbor_list(n)
+            idx_dummy = None
+            with jax.named_scope("sph.rebuild.mass_table"):
+                m_table = ops.mass_table(
+                    binning, st.fluid.m, cfg.policy.records_dtype,
+                    carry.m_scale,
+                )
+        else:
+            with jax.named_scope("sph.rebuild.search"):
+                nl = _packed_neighbor_list(cfg, ps)
+            overflow = overflow | nl.overflowed
+            win_bad = nl.overflowed
+            if nl.trunc is not None:
+                win_bad = win_bad | nl.trunc
+            flags = health.fold_flag(flags, win_bad, health.WINDOW_TRUNC)
+            # The window search already pads invalid slots with the dummy
+            # id N — the fused sweep reads nl.idx directly (idx_dummy
+            # stays None: carrying nl.idx twice would alias two donated
+            # buffers). Only the table-oracle list (garbage invalid
+            # slots) sanitizes.
+            idx_dummy = (
+                fused._sanitized_idx(nl, n)
+                if cfg.resolved_backend == "xla" and cfg.window is None
+                else None
+            )
+        return PersistentCarry(
+            st=st,
+            order=order,
+            nl=nl,
+            disp_acc=jnp.zeros_like(carry.disp_acc),
+            rebuilds=carry.rebuilds + 1,
+            steps=carry.steps,
+            overflow=overflow,
+            binning=binning,
+            idx_dummy=idx_dummy,
+            m_scale=carry.m_scale,
+            m_table=m_table,
+            flags=flags,
         )
-    else:
-        nl = _packed_neighbor_list(cfg, ps)
-        overflow = overflow | nl.overflowed
-        win_bad = nl.overflowed
-        if nl.trunc is not None:
-            win_bad = win_bad | nl.trunc
-        flags = health.fold_flag(flags, win_bad, health.WINDOW_TRUNC)
-        # The window search already pads invalid slots with the dummy
-        # id N — the fused sweep reads nl.idx directly (idx_dummy stays
-        # None: carrying nl.idx twice would alias two donated buffers).
-        # Only the table-oracle list (garbage invalid slots) sanitizes.
-        idx_dummy = (
-            fused._sanitized_idx(nl, n)
-            if cfg.resolved_backend == "xla" and cfg.window is None
-            else None
-        )
-    return PersistentCarry(
-        st=st,
-        order=order,
-        nl=nl,
-        disp_acc=jnp.zeros_like(carry.disp_acc),
-        rebuilds=carry.rebuilds + 1,
-        steps=carry.steps,
-        overflow=overflow,
-        binning=binning,
-        idx_dummy=idx_dummy,
-        m_scale=carry.m_scale,
-        m_table=m_table,
-        flags=flags,
-    )
 
 
 def init_persistent(cfg: SPHConfig, state: SPHState) -> PersistentCarry:
@@ -694,46 +704,48 @@ def _physics_step(
     if dt is None:
         dt = cfg.dt
     st, fl = carry.st, carry.st.fluid
-    drho, acc = _FORCE_BACKENDS[cfg.resolved_backend](cfg, carry)
-    rho = fl.rho + dt * drho
-    if cfg.wall_rho_clamp:
-        rho = jnp.where(st.fixed, jnp.maximum(rho, sch.rho0), rho)
+    with jax.named_scope("sph.force"):
+        drho, acc = _FORCE_BACKENDS[cfg.resolved_backend](cfg, carry)
+    with jax.named_scope("sph.integrate"):
+        rho = fl.rho + dt * drho
+        if cfg.wall_rho_clamp:
+            rho = jnp.where(st.fixed, jnp.maximum(rho, sch.rho0), rho)
 
-    bf = sch.body_force_vec(dom.dim)
-    v = fl.v + dt * (acc + bf)
-    # Walls: prescribed velocity (0 or v_wall), never advected. The
-    # prescribed values flow into the next step's pair sums through the
-    # same v array (and thus the fused record rows) as fluid velocities.
-    vw = 0.0 if st.v_wall is None else st.v_wall
-    v = jnp.where(st.fixed[:, None], vw, v)
+        bf = sch.body_force_vec(dom.dim)
+        v = fl.v + dt * (acc + bf)
+        # Walls: prescribed velocity (0 or v_wall), never advected. The
+        # prescribed values flow into the next step's pair sums through the
+        # same v array (and thus the fused record rows) as fluid velocities.
+        vw = 0.0 if st.v_wall is None else st.v_wall
+        v = jnp.where(st.fixed[:, None], vw, v)
 
-    dxn = jnp.where(
-        st.fixed[:, None], 0.0, v * dt * (2.0 / dom.h_d)
-    ).astype(jnp.float32)
-    rc = rcll.advance(dom, st.rc, dxn, dtype=pol.coords_dtype)
-    st2 = SPHState(
-        xn=st.xn,
-        rc=rc,
-        fluid=sph.FluidState(v=v, rho=rho, m=fl.m),
-        fixed=st.fixed,
-        t=st.t + dt,
-        kind=st.kind,
-        v_wall=st.v_wall,
-    )
-    return PersistentCarry(
-        st=st2,
-        order=carry.order,
-        nl=carry.nl,
-        disp_acc=carry.disp_acc + dxn,
-        rebuilds=carry.rebuilds,
-        steps=carry.steps + 1,
-        overflow=carry.overflow,
-        binning=carry.binning,
-        idx_dummy=carry.idx_dummy,
-        m_scale=carry.m_scale,
-        m_table=carry.m_table,
-        flags=carry.flags,
-    )
+        dxn = jnp.where(
+            st.fixed[:, None], 0.0, v * dt * (2.0 / dom.h_d)
+        ).astype(jnp.float32)
+        rc = rcll.advance(dom, st.rc, dxn, dtype=pol.coords_dtype)
+        st2 = SPHState(
+            xn=st.xn,
+            rc=rc,
+            fluid=sph.FluidState(v=v, rho=rho, m=fl.m),
+            fixed=st.fixed,
+            t=st.t + dt,
+            kind=st.kind,
+            v_wall=st.v_wall,
+        )
+        return PersistentCarry(
+            st=st2,
+            order=carry.order,
+            nl=carry.nl,
+            disp_acc=carry.disp_acc + dxn,
+            rebuilds=carry.rebuilds,
+            steps=carry.steps + 1,
+            overflow=carry.overflow,
+            binning=carry.binning,
+            idx_dummy=carry.idx_dummy,
+            m_scale=carry.m_scale,
+            m_table=carry.m_table,
+            flags=carry.flags,
+        )
 
 
 def exact_neighbor_list(
@@ -771,8 +783,10 @@ def step_persistent(cfg: SPHConfig, carry: PersistentCarry) -> PersistentCarry:
         # particle's spiked displacement can trigger the Verlet rebuild
         # in the SAME step (the overlap must reach the neighbor list).
         carry = health.inject_fault(cfg.fault, carry)
+    with jax.named_scope("sph.skin_check"):
+        rebuild = _needs_rebuild(cfg, carry)
     carry = jax.lax.cond(
-        _needs_rebuild(cfg, carry),
+        rebuild,
         lambda c: _rebuild(cfg, c),
         lambda c: c,
         carry,

@@ -330,6 +330,39 @@ def mass_table(
     return _typed_row_table(binning, m, records_dtype)
 
 
+def force_grid_work(
+    domain: Domain, binning: cells_lib.CellBinning
+) -> tuple[int, Array]:
+    """Grid steps of one force pass: ``(launched, useful)``.
+
+    ``launched`` (a static int) is the size of the grid the force kernel
+    is launched over (:func:`rcll_force.force_grid`). ``useful`` (an
+    int32 scalar) counts the steps whose self cell and neighbour cell
+    both hold a particle under ``binning``, the tables' binning; an
+    out-of-domain neighbour is the empty sentinel cell.
+    """
+    ncells = tuple(int(n) for n in domain.ncells)
+    C, M = rcll_force.force_grid(ncells)
+    return C * M, _useful_grid_steps(
+        binning.counts, ncells=ncells,
+        periodic=tuple(bool(p) for p in domain.periodic),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("ncells", "periodic"))
+def _useful_grid_steps(counts: Array, *, ncells: tuple,
+                       periodic: tuple) -> Array:
+    C, M = rcll_force.force_grid(ncells)
+    occupied = jnp.concatenate([counts > 0, jnp.zeros((1,), bool)])
+    c = jnp.arange(C, dtype=jnp.int32)
+    useful = jnp.zeros((), jnp.int32)
+    for k in range(M):
+        nb = rcll_force.neighbor_cell(c, jnp.int32(k), ncells=ncells,
+                                      periodic=periodic)
+        useful += jnp.sum(occupied[:C] & occupied[nb], dtype=jnp.int32)
+    return useful
+
+
 def rcll_force_particles(
     domain: Domain,
     binning: cells_lib.CellBinning,
@@ -390,7 +423,6 @@ def rcll_force_particles(
         scheme = scheme_lib.wcsph(c0, rho0, mu)
     interpret = default_interpret() if interpret is None else interpret
     d = rc.rel.shape[1]
-    delta = domain.wrap_cell_delta(rc.cell_xy - binning.cell_xy)
     half = jnp.dtype(records_dtype).itemsize == 2
     if not half:
         m_scale = jnp.float32(1.0)
@@ -399,51 +431,54 @@ def rcll_force_particles(
     if m_table is None:
         m_table = mass_table(binning, m, records_dtype, m_scale)
 
-    def u16(x):
-        return jax.lax.bitcast_convert_type(x, jnp.uint16)
+    with jax.named_scope("sph.cell_tables"):
+        delta = domain.wrap_cell_delta(rc.cell_xy - binning.cell_xy)
 
-    # One 16-bit record slab + one fp32 slab: the dynamic halves of the
-    # step, packed cell-major in ONE sweep (contiguous slices — the
-    # arrays are cell-sorted). Each field rides the slab of its OWN
-    # storage width: rel keeps its raw storage bits (fp16/bf16 in the
-    # 16-bit slab, fp32-coords policies like APPROACH_I in the fp32
-    # slab — never quantized), shift is always an exact small int16,
-    # v follows the records dtype.
-    rel_half = jnp.dtype(rc.rel.dtype).itemsize == 2
-    cols16 = [u16(delta.astype(jnp.int16))]
-    cols32 = [(1.0 / rho).astype(jnp.float32)[:, None]]
-    fill32 = [1.0 / scheme.rho0]
-    if rel_half:
-        cols16.insert(0, u16(rc.rel))
-    else:
-        cols32.append(rc.rel.astype(jnp.float32))
-        fill32 += [0.0] * d
-    if half:
-        cols16.append(u16(v.astype(records_dtype)))
-    else:
-        cols32.append(v.astype(jnp.float32))
-        fill32 += [0.0] * d
-    t16, t32 = cell_tables(
-        jnp.concatenate(cols16, axis=1),
-        jnp.concatenate(cols32, axis=1),
-        binning.counts,
-        jnp.asarray(fill32, jnp.float32),
-        cap=binning.table.shape[1],
-    )
-    o16 = d if rel_half else 0  # 16-bit slab offset past rel
-    o32 = 1 + (0 if rel_half else d)  # fp32 slab offset past inv, rel
-    if rel_half:
-        rel_t = jax.lax.bitcast_convert_type(t16[:, :d], rc.rel.dtype)
-    else:
-        rel_t = t32[:, 1:1 + d]
-    shift_t = jax.lax.bitcast_convert_type(t16[:, o16:o16 + d], jnp.int16)
-    if half:
-        v_t = jax.lax.bitcast_convert_type(
-            t16[:, o16 + d:o16 + 2 * d], records_dtype
+        def u16(x):
+            return jax.lax.bitcast_convert_type(x, jnp.uint16)
+
+        # One 16-bit record slab + one fp32 slab: the dynamic halves of the
+        # step, packed cell-major in ONE sweep (contiguous slices — the
+        # arrays are cell-sorted). Each field rides the slab of its OWN
+        # storage width: rel keeps its raw storage bits (fp16/bf16 in the
+        # 16-bit slab, fp32-coords policies like APPROACH_I in the fp32
+        # slab — never quantized), shift is always an exact small int16,
+        # v follows the records dtype.
+        rel_half = jnp.dtype(rc.rel.dtype).itemsize == 2
+        cols16 = [u16(delta.astype(jnp.int16))]
+        cols32 = [(1.0 / rho).astype(jnp.float32)[:, None]]
+        fill32 = [1.0 / scheme.rho0]
+        if rel_half:
+            cols16.insert(0, u16(rc.rel))
+        else:
+            cols32.append(rc.rel.astype(jnp.float32))
+            fill32 += [0.0] * d
+        if half:
+            cols16.append(u16(v.astype(records_dtype)))
+        else:
+            cols32.append(v.astype(jnp.float32))
+            fill32 += [0.0] * d
+        t16, t32 = cell_tables(
+            jnp.concatenate(cols16, axis=1),
+            jnp.concatenate(cols32, axis=1),
+            binning.counts,
+            jnp.asarray(fill32, jnp.float32),
+            cap=binning.table.shape[1],
         )
-    else:
-        v_t = t32[:, o32:o32 + d]
-    inv_t = t32[:, 0]
+        o16 = d if rel_half else 0  # 16-bit slab offset past rel
+        o32 = 1 + (0 if rel_half else d)  # fp32 slab offset past inv, rel
+        if rel_half:
+            rel_t = jax.lax.bitcast_convert_type(t16[:, :d], rc.rel.dtype)
+        else:
+            rel_t = t32[:, 1:1 + d]
+        shift_t = jax.lax.bitcast_convert_type(t16[:, o16:o16 + d], jnp.int16)
+        if half:
+            v_t = jax.lax.bitcast_convert_type(
+                t16[:, o16 + d:o16 + 2 * d], records_dtype
+            )
+        else:
+            v_t = t32[:, o32:o32 + d]
+        inv_t = t32[:, 0]
     drho_t, acc_t = rcll_force.rcll_force(
         rel_t, shift_t, v_t, m_table, inv_t,
         ncells=tuple(domain.ncells),
@@ -454,6 +489,8 @@ def rcll_force_particles(
         scheme=scheme,
         interpret=interpret,
     )
-    drho = unpack_per_particle(drho_t, binning) * m_scale
-    acc = unpack_per_particle(acc_t.transpose(0, 2, 1), binning) * m_scale
+    with jax.named_scope("sph.unpack"):
+        drho = unpack_per_particle(drho_t, binning) * m_scale
+        acc = unpack_per_particle(acc_t.transpose(0, 2, 1), binning)
+        acc = acc * m_scale
     return drho, acc

@@ -186,6 +186,18 @@ def neighbor_cell(c, k, *, ncells: tuple, periodic: tuple):
     return jnp.where(valid, flat, total)
 
 
+def force_grid(ncells: tuple) -> tuple[int, int]:
+    """The kernel's ``pallas_call`` grid: (C self cells, 3^dim neighbours).
+
+    Every grid step runs, whatever the occupancy of its two cells.
+    """
+    from repro.core import cells  # deferred: kernels stay import-light
+
+    dim = len(ncells)
+    return (int(np.prod(ncells)),
+            int(cells.neighbor_cell_offsets(dim).shape[0]))
+
+
 def _words(x: Array) -> Array:
     """16-bit floats travel as their int16 words (Mosaic loads those)."""
     if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype.itemsize == 2:
@@ -224,11 +236,10 @@ def rcll_force(
     from repro.core import cells  # deferred: kernels stay import-light
 
     c1, d, cap = rel.shape
-    C = int(np.prod(ncells))
+    C, M = grid = force_grid(tuple(ncells))
     if c1 != C + 1:
         raise ValueError(f"tables hold {c1} cells; grid {ncells} needs {C + 1}")
     offs = cells.neighbor_cell_offsets(dim)
-    M = offs.shape[0]
     offs_flat = jnp.asarray(offs.astype(np.float32).reshape(M * d))
     kernel = functools.partial(
         _force_kernel,
@@ -255,7 +266,7 @@ def rcll_force(
     inv_w = inv_rho.astype(jnp.float32).reshape(c1, 1, cap)
     drho, acc = pl.pallas_call(
         kernel,
-        grid=(C, M),
+        grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             cell_block(d), nbcell_block(d),  # rel i, j

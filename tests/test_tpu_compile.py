@@ -13,6 +13,7 @@ only one process may load the TPU library, and every test worker
 imports this file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -127,3 +128,55 @@ def test_cell_pack_compiles_for_v5e(one_chip, key):
         spec((1,), jnp.float32),
     ).compile()
     _fits(compiled)
+
+
+_MOVES = ("gather", "dynamic-slice", "dynamic-update-slice", "scatter",
+          "while", "reduce-window")
+
+
+def _computations(hlo_text: str) -> dict:
+    """{computation: [(opcode, op path, called computation)]}."""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            cur = comps.setdefault(line.split("(")[0].split()[-1]
+                                   .lstrip("%"), [])
+        elif cur is not None and " = " in line:
+            rhs = line.split(" = ", 1)[1]
+            op = re.search(r"\s([a-z][a-z\-]*)\(", " " + rhs)
+            path = re.search(r'op_name="([^"]*)"', rhs)
+            calls = re.search(r"calls=%?([\w.\-]+)", rhs)
+            cur.append((op.group(1) if op else "", path.group(1) if path
+                        else "", calls.group(1) if calls else None))
+    return comps
+
+
+def test_step_scopes_survive_the_tpu_compile(one_chip, monkeypatch):
+    """``run_persistent`` compiled for the v5e at a small dam break: the
+    force kernel sits under ``sph.force``, and no gather or window loop
+    of the cell-table pack or of the unpack is fused into an op of
+    ``sph.integrate`` (a fusion counts under its root's scope; only the
+    unpack's elementwise mass rescale joins the density update). The
+    CPU compiler fuses the unpack's gather into the update; the chip's
+    does not, and this is the program the chip runs."""
+    from repro.core import solver
+
+    case = cases.build_case("dam_break", ds=0.1, backend="pallas")
+    cfg, st = case.build()
+    carry = solver.init_persistent(cfg, st)
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    specs = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), carry)
+    text = solver.run_persistent.lower(cfg, specs, 2).compile().as_text()
+    comps = _computations(text)
+    kernel = [p for ins in comps.values() for op, p, _ in ins
+              if op == "custom-call" and p.endswith("rcll_force/pallas_call")]
+    assert kernel and all("/sph.force/" in p for p in kernel), kernel
+    for ins in comps.values():
+        for op, path, called in ins:
+            if op != "fusion" or "sph.integrate" not in path.split("/"):
+                continue
+            for inner_op, inner, _ in comps[called]:
+                parts = inner.split("/")
+                if "sph.cell_tables" in parts or "sph.unpack" in parts:
+                    assert inner_op not in _MOVES, (path, inner_op, inner)
