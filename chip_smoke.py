@@ -19,7 +19,8 @@ Phases, in order; any failure exits non-zero:
       ``peak_bytes_in_use``.
   (c) agreement — ``dam_break`` at n=64k, 10 steps on ``pallas`` and on
       ``xla``: positions, velocities and densities agree within the
-      trajectory tolerance of ``tests/test_fused_force.py``.
+      trajectory tolerance of ``tests/test_fused_force.py``; then 10 more
+      steps each, compiled, are timed.
 
 With ``--chips 4`` only the service runs: over four workers pinned one
 per chip, with two more buckets (n=32000) so that every chip owns a
@@ -250,6 +251,10 @@ def phase_agreement(jax) -> None:
         log(f"agreement: {backend} N={sim.n_particles} {AGREE_STEPS} steps"
             f" in {time.perf_counter() - t0:.1f}s (compile included), "
             f"rebuilds={int(res.stats.rebuilds)}")
+        t0 = time.perf_counter()
+        jax.block_until_ready(sim.run(AGREE_STEPS).state)
+        log(f"agreement: {backend} warm {AGREE_STEPS} steps in "
+            f"{time.perf_counter() - t0!r}s")
     for name, a, b in zip(("x", "v", "rho"), out["pallas"], out["xla"]):
         err = float(np.max(np.abs(a - b)))
         check(np.isfinite(a).all() and np.isfinite(b).all(),

@@ -44,10 +44,10 @@ neighbor producer:
     (``core/fused.py``): pair geometry decoded and consumed in chunks of
     packed (cell-sorted) rows, peak pair memory O(chunk*K*d).
   * ``"pallas"`` - Pallas neighbor tables + Pallas fused force kernels
-    (``kernels/rcll_force.py``): per (cell, neighbor-cell) tile, Eq. 7
-    decode + B-spline gradient + continuity/momentum accumulation in
-    VMEM; no neighbor list is consumed at all (compact support masks
-    out-of-range candidates exactly).
+    (``kernels/rcll_force.py``): per row of cells, all 3^d neighbour
+    offsets, Eq. 7 decode + B-spline gradient + continuity/momentum
+    accumulation in VMEM; no neighbor list is consumed at all (compact
+    support masks out-of-range candidates exactly).
 
 The default is pallas on TPU and xla elsewhere, so CPU tests always
 exercise the fused path with the reference path as the test oracle.
@@ -256,8 +256,8 @@ class PersistentCarry(NamedTuple):
     # reduction (a sync point in the chunked sweep) is hoisted out of
     # the scan entirely. None on paths that don't consume it.
     m_scale: Array | None = None
-    # Pallas backend only: the static cell-major mass tile
-    # (ops.mass_table). Masses never change, so it is rebuilt only when
+    # Pallas backend only: the static mass table in the force kernel's
+    # row layout (ops.mass_table). Masses never change, so it is rebuilt only when
     # the packed ORDER changes (i.e. at rebuild) — the per-step tile
     # refresh then touches exactly the coordinate/velocity/density
     # halves of the record stream.
@@ -459,7 +459,7 @@ def _rebuild(cfg: SPHConfig, carry: PersistentCarry) -> PersistentCarry:
             idx_dummy = None
             with jax.named_scope("sph.rebuild.mass_table"):
                 m_table = ops.mass_table(
-                    binning, st.fluid.m, cfg.policy.records_dtype,
+                    cfg.domain, binning, st.fluid.m, cfg.policy.records_dtype,
                     carry.m_scale,
                 )
         else:

@@ -1,10 +1,13 @@
 """Public jit'd wrappers for the Pallas kernels + cell-table packing.
 
-The kernels consume *cell-major* dense tables (C+1, d, cap) - the packing
-here is the TPU analogue of the paper's particle sort (particles that share
-a cell are contiguous; row-major cell order keeps spatial neighbors close
-in HBM). Row C is a sentinel empty cell: out-of-domain neighborhood slots
-point at it, so the kernels never branch on validity.
+The (cell, neighbour cell) kernels consume *cell-major* dense tables
+(C+1, d, cap) - the packing here is the TPU analogue of the paper's
+particle sort (particles that share a cell are contiguous; row-major cell
+order keeps spatial neighbors close in HBM). Row C is a sentinel empty
+cell: out-of-domain neighborhood slots point at it, so the kernels never
+branch on validity. The force kernel consumes the same cells in its row
+layout (:func:`cell_tables`, ``kernels/rcll_force.py``): ghost cells in
+place of the sentinel, cells on the lanes.
 
 ``interpret=None`` resolves through :func:`default_interpret`: compiled
 on TPU, interpreted on every other backend (tests on the CPU). All
@@ -12,8 +15,6 @@ wrappers are shape-polymorphic over (C, cap, d, M).
 """
 from __future__ import annotations
 
-import functools
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -38,21 +39,6 @@ def nb_with_sentinel(domain: Domain) -> Array:
     nb = jnp.asarray(cell_neighbor_ids(domain))
     return jnp.concatenate(
         [nb, jnp.full((1, nb.shape[1]), nb.shape[0], nb.dtype)], axis=0
-    )
-
-
-def _row_table(
-    binning: cells_lib.CellBinning, f: Array, fill: float = 0.0
-) -> Array:
-    """(C+1, cap) f32 cell-major table of a per-particle scalar field.
-
-    ``fill`` value for empty slots and the sentinel row — pass a nonzero
-    fill for fields that appear in denominators (e.g. rho) so masked
-    pair terms stay an exact 0 instead of 0 * inf = NaN.
-    """
-    ft = cells_lib.to_cell_major(binning, f.astype(jnp.float32), fill=fill)
-    return jnp.concatenate(
-        [ft, jnp.full((1, ft.shape[1]), fill, ft.dtype)], axis=0
     )
 
 
@@ -126,40 +112,51 @@ def cell_tables(
     fill32: Array,  # (F32,) f32 empty-slot fill per fp32 column
     *,
     cap: int,
+    ncells: tuple,
+    periodic: tuple,
 ) -> tuple[Array, Array]:
-    """Cell-major tables from cell-sorted rows: one window per cell.
+    """The force kernel's row-layout tables from cell-sorted rows.
 
     The persistent pipeline's arrays are cell-sorted, so cell c's
     particles are the contiguous rows ``starts[c] .. starts[c] +
     counts[c] - 1`` (the counting-sort invariant): each cell's tile is
     the ``cap``-row window at ``starts[c]`` of a record slab, masked past
     the occupancy — one windowed gather per slab instead of one id-table
-    gather per field. Returns ``(t16 (C+1, F16, cap) u16, t32 (C+1,
-    F32, cap) f32)``: row C is the sentinel empty cell (fp32 columns hold
-    their fill so denominator fields stay finite).
+    gather per field. The windows are taken at every cell of the
+    ghost-padded grid (``rcll_force.padded_cell_ids``: a periodic ghost
+    reads the opposite edge's window, a wall ghost an empty one), then
+    laid out as ``(n0+2, [n1+2,] F, cap8, W)`` (``rcll_force.to_rows``):
+    ``t16`` u16 with 0 in empty slots, ``t32`` f32 with each column's
+    fill there, so denominator fields stay finite.
     """
-    n = rows16.shape[0]
-    starts = jnp.concatenate(
-        [cells_lib.exclusive_cumsum(counts), jnp.full((1,), n, jnp.int32)]
-    )
-    counts_s = jnp.concatenate(
-        [counts.astype(jnp.int32), jnp.zeros((1,), jnp.int32)]
-    )
-    slot = jnp.arange(cap, dtype=jnp.int32)[None, :]
-    occ = (slot < counts_s[:, None])[..., None]  # (C+1, cap, 1)
+    ids = jnp.asarray(rcll_force.padded_cell_ids(ncells, periodic).ravel())
+    starts = jnp.concatenate([cells_lib.exclusive_cumsum(counts),
+                              jnp.full((1,), rows16.shape[0], jnp.int32)])
+    cnt = jnp.concatenate([counts.astype(jnp.int32),
+                           jnp.zeros((1,), jnp.int32)])  # C: the empty cell
+    starts, cnt = starts[ids], cnt[ids]
+    t16 = rcll_force.to_rows(_windows(rows16, starts, cnt, cap, 0), ncells)
+    t32 = rcll_force.to_rows(
+        _windows(rows32, starts, cnt, cap, fill32[None, :]), ncells)
+    return t16, t32
 
-    def windows(rows):
-        # cap rows of padding: the sentinel's window (and a full last
-        # cell's) never reads past the slab
-        pad = jnp.concatenate([rows, jnp.zeros((cap,) + rows.shape[1:],
-                                               rows.dtype)])
-        return jax.vmap(
-            lambda s: jax.lax.dynamic_slice_in_dim(pad, s, cap, 0)
-        )(starts)  # (C+1, cap, F)
 
-    t16 = jnp.where(occ, windows(rows16), 0)
-    t32 = jnp.where(occ, windows(rows32), fill32[None, None, :])
-    return t16.transpose(0, 2, 1), t32.transpose(0, 2, 1)
+def _windows(rows: Array, starts: Array, cnt: Array, cap: int,
+             fill) -> Array:
+    """``(len(starts), cap8, F)``: the ``cap8``-row window at each start
+    (``rcll_force.slot_rows``), ``fill`` past its occupancy and past
+    ``cap``."""
+    cap8 = rcll_force.slot_rows(cap)
+    # cap8 rows of padding: an empty window (and a full last cell's)
+    # never reads past the slab
+    pad = jnp.concatenate([rows, jnp.zeros((cap8,) + rows.shape[1:],
+                                           rows.dtype)])
+    win = jax.vmap(
+        lambda s: jax.lax.dynamic_slice_in_dim(pad, s, cap8, 0)
+    )(starts)
+    occ = jnp.arange(cap8, dtype=jnp.int32)[None, :] < jnp.minimum(
+        cnt, cap)[:, None]
+    return jnp.where(occ[..., None], win, fill)
 
 
 # --------------------------------------------------------------------------
@@ -297,28 +294,22 @@ def rcll_gradient_particles(
 # --------------------------------------------------------------------------
 # Fused RCLL force pass (kernels/rcll_force.py wrappers)
 # --------------------------------------------------------------------------
-def _typed_row_table(
-    binning: cells_lib.CellBinning, f: Array, dtype, fill: float = 0.0
-) -> Array:
-    """(C+1, cap) cell-major table of a per-particle scalar at ``dtype``."""
-    ft = cells_lib.to_cell_major(binning, f.astype(dtype), fill=fill)
-    return jnp.concatenate(
-        [ft, jnp.full((1, ft.shape[1]), fill, ft.dtype)], axis=0
-    )
-
-
 def mass_table(
+    domain: Domain,
     binning: cells_lib.CellBinning,
     m: Array,
     records_dtype,
     m_scale: Array | None = None,
 ) -> Array:
-    """(C+1, cap) static cell-major mass table for the force kernel.
+    """``(n0+2, [n1+2,] 1, cap8, W)`` static mass table for the force
+    kernel, in its row layout (:func:`cell_tables`).
 
     Masses never change during a run, so the persistent solver builds
     this once per REBUILD (packed order changes there) instead of once
     per step; half-width layouts store ``m / m_scale``
-    (``fused.mass_scale`` — see the subnormal-mass note there).
+    (``fused.mass_scale`` — see the subnormal-mass note there). Its slots
+    are :func:`cell_tables`' under the PACKED binning (slot s of cell c
+    holds particle ``starts[c] + s`` in both).
     """
     from repro.core import fused
 
@@ -327,40 +318,33 @@ def mass_table(
         if m_scale is None:
             m_scale = fused.mass_scale(m)
         m = m.astype(jnp.float32) / m_scale
-    return _typed_row_table(binning, m, records_dtype)
+    cap = binning.table.shape[1]
+    mt = cells_lib.to_cell_major(binning, m.astype(records_dtype)[:, None])
+    mt = jnp.pad(mt, [(0, 1), (0, rcll_force.slot_rows(cap) - cap), (0, 0)])
+    ids = rcll_force.padded_cell_ids(domain.ncells, domain.periodic)
+    return rcll_force.to_rows(mt[ids.ravel()], domain.ncells)
 
 
 def force_grid_work(
     domain: Domain, binning: cells_lib.CellBinning
 ) -> tuple[int, Array]:
-    """Grid steps of one force pass: ``(launched, useful)``.
+    """Force-kernel work of one pass, in (self cell, neighbour offset)
+    pairs: ``(launched, useful)``.
 
-    ``launched`` (a static int) is the size of the grid the force kernel
-    is launched over (:func:`rcll_force.force_grid`). ``useful`` (an
-    int32 scalar) counts the steps whose self cell and neighbour cell
-    both hold a particle under ``binning``, the tables' binning; an
-    out-of-domain neighbour is the empty sentinel cell.
+    ``launched`` (a static int) counts what the kernel evaluates: grid
+    steps (:func:`rcll_force.force_grid`) × the cells of a step, ghost
+    and padding lanes included (:func:`rcll_force.lane_width`) × 3^dim.
+    ``useful`` (an int32 scalar) counts the pairs whose self cell and
+    neighbour cell both hold a particle under ``binning``, the tables'
+    binning; an out-of-domain neighbour is empty.
     """
     ncells = tuple(int(n) for n in domain.ncells)
-    C, M = rcll_force.force_grid(ncells)
-    return C * M, _useful_grid_steps(
-        binning.counts, ncells=ncells,
-        periodic=tuple(bool(p) for p in domain.periodic),
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("ncells", "periodic"))
-def _useful_grid_steps(counts: Array, *, ncells: tuple,
-                       periodic: tuple) -> Array:
-    C, M = rcll_force.force_grid(ncells)
-    occupied = jnp.concatenate([counts > 0, jnp.zeros((1,), bool)])
-    c = jnp.arange(C, dtype=jnp.int32)
-    useful = jnp.zeros((), jnp.int32)
-    for k in range(M):
-        nb = rcll_force.neighbor_cell(c, jnp.int32(k), ncells=ncells,
-                                      periodic=periodic)
-        useful += jnp.sum(occupied[:C] & occupied[nb], dtype=jnp.int32)
-    return useful
+    launched = (int(np.prod(rcll_force.force_grid(ncells)))
+                * rcll_force.lane_width(ncells[-1]) * 3 ** len(ncells))
+    occupied = jnp.concatenate([binning.counts > 0, jnp.zeros((1,), bool)])
+    nb = cell_neighbor_ids(domain)  # (C, 3^d), C where off a wall
+    useful = jnp.sum(occupied[:-1, None] & occupied[nb], dtype=jnp.int32)
+    return launched, useful
 
 
 def rcll_force_particles(
@@ -429,7 +413,8 @@ def rcll_force_particles(
     elif m_scale is None:
         m_scale = fused.mass_scale(m)
     if m_table is None:
-        m_table = mass_table(binning, m, records_dtype, m_scale)
+        m_table = mass_table(domain, binning, m, records_dtype, m_scale)
+    cap = binning.table.shape[1]
 
     with jax.named_scope("sph.cell_tables"):
         delta = domain.wrap_cell_delta(rc.cell_xy - binning.cell_xy)
@@ -439,58 +424,53 @@ def rcll_force_particles(
 
         # One 16-bit record slab + one fp32 slab: the dynamic halves of the
         # step, packed cell-major in ONE sweep (contiguous slices — the
-        # arrays are cell-sorted). Each field rides the slab of its OWN
-        # storage width: rel keeps its raw storage bits (fp16/bf16 in the
-        # 16-bit slab, fp32-coords policies like APPROACH_I in the fp32
-        # slab — never quantized), shift is always an exact small int16,
-        # v follows the records dtype.
-        rel_half = jnp.dtype(rc.rel.dtype).itemsize == 2
-        cols16 = [u16(delta.astype(jnp.int16))]
-        cols32 = [(1.0 / rho).astype(jnp.float32)[:, None]]
-        fill32 = [1.0 / scheme.rho0]
-        if rel_half:
-            cols16.insert(0, u16(rc.rel))
-        else:
-            cols32.append(rc.rel.astype(jnp.float32))
-            fill32 += [0.0] * d
-        if half:
-            cols16.append(u16(v.astype(records_dtype)))
-        else:
-            cols32.append(v.astype(jnp.float32))
-            fill32 += [0.0] * d
+        # arrays are cell-sorted), columns in the kernel's
+        # ``slab_fields`` order.
+        cols = {
+            "rel": rc.rel,
+            "shift": delta.astype(jnp.int16),
+            "v": v.astype(records_dtype),
+            "inv": (1.0 / rho).astype(jnp.float32)[:, None],
+        }
+        fill = {"inv": [1.0 / scheme.rho0], "rel": [0.0] * d, "v": [0.0] * d}
+        f16, f32 = rcll_force.slab_fields(rc.rel.dtype, records_dtype)
         t16, t32 = cell_tables(
-            jnp.concatenate(cols16, axis=1),
-            jnp.concatenate(cols32, axis=1),
+            jnp.concatenate([u16(cols[f]) for f in f16], axis=1),
+            jnp.concatenate([cols[f].astype(jnp.float32) for f in f32],
+                            axis=1),
             binning.counts,
-            jnp.asarray(fill32, jnp.float32),
-            cap=binning.table.shape[1],
+            jnp.asarray([x for f in f32 for x in fill[f]], jnp.float32),
+            cap=cap,
+            ncells=tuple(domain.ncells),
+            periodic=tuple(domain.periodic),
         )
-        o16 = d if rel_half else 0  # 16-bit slab offset past rel
-        o32 = 1 + (0 if rel_half else d)  # fp32 slab offset past inv, rel
-        if rel_half:
-            rel_t = jax.lax.bitcast_convert_type(t16[:, :d], rc.rel.dtype)
-        else:
-            rel_t = t32[:, 1:1 + d]
-        shift_t = jax.lax.bitcast_convert_type(t16[:, o16:o16 + d], jnp.int16)
-        if half:
-            v_t = jax.lax.bitcast_convert_type(
-                t16[:, o16 + d:o16 + 2 * d], records_dtype
-            )
-        else:
-            v_t = t32[:, o32:o32 + d]
-        inv_t = t32[:, 0]
-    drho_t, acc_t = rcll_force.rcll_force(
-        rel_t, shift_t, v_t, m_table, inv_t,
-        ncells=tuple(domain.ncells),
-        periodic=tuple(domain.periodic),
+    out = rcll_force.rcll_force(
+        t16, t32, m_table,
         hc_phys=tuple(domain.cell_sizes),
         h=domain.h,
         dim=domain.dim,
+        rel_dtype=rc.rel.dtype,
+        records_dtype=records_dtype,
         scheme=scheme,
+        cap=cap,
         interpret=interpret,
     )
     with jax.named_scope("sph.unpack"):
-        drho = unpack_per_particle(drho_t, binning) * m_scale
-        acc = unpack_per_particle(acc_t.transpose(0, 2, 1), binning)
-        acc = acc * m_scale
-    return drho, acc
+        out = unpack_rows(out, binning, tuple(domain.ncells)) * m_scale
+    return out[:, 0], out[:, 1:]
+
+
+def unpack_rows(out: Array, binning: cells_lib.CellBinning,
+                ncells: tuple) -> Array:
+    """Per-particle rows ``(N, F)`` of the kernel's row-layout output.
+
+    Particle p of the PACKED binning sits in slot ``p - starts[cell]``
+    of its cell (the counting-sort invariant :func:`cell_tables` packs
+    by). A particle past ``cap`` (a cell overflow, flagged by the
+    binning) reads the last slot.
+    """
+    n = binning.cell_id.shape[0]
+    slot = jnp.arange(n, dtype=jnp.int32) - cells_lib.exclusive_cumsum(
+        binning.counts)[binning.cell_id]
+    slot = jnp.clip(slot, 0, binning.table.shape[1] - 1)
+    return rcll_force.from_rows(out, binning.cell_id, slot, ncells)

@@ -1,12 +1,13 @@
 """Shared cell-pair tile math for the RCLL Pallas kernels.
 
-Every cell-blocked kernel in this package (``nnps_pairwise``,
-``sph_gradient``, ``rcll_force``) walks the same structure: grid (C, M),
-block (c, k) holding the self cell's (d, cap) coordinate tile and the
-k-th neighbor cell's tile (scalar-prefetched ``nb_ids``), with the
-neighborhood offset as the exact Eq. (7) integer anchor. These helpers
-are that structure's tile math, factored once so a change to the
-distance arithmetic or masking cannot diverge between kernels.
+The (cell, neighbour cell) kernels of this package (``nnps_pairwise``,
+``sph_gradient``) walk the same structure: grid (C, M), block (c, k)
+holding the self cell's (d, cap) coordinate tile and the k-th neighbor
+cell's tile (scalar-prefetched ``nb_ids``), with the neighborhood offset
+as the exact Eq. (7) integer anchor. These helpers are that structure's
+tile math, factored once so a change to the distance arithmetic or
+masking cannot diverge between kernels; the 16-bit decodes serve the
+row-layout force kernel (``rcll_force``) too.
 
 All functions are plain jnp on (d, cap)/(cap,) tiles — they trace inside
 ``pallas_call`` bodies and in the pure-jnp oracles (``kernels/ref.py``)
@@ -69,38 +70,6 @@ def tile_phys_disp(
     return disp, r2
 
 
-def tile_phys_disp_shifted(
-    rel_i: Array,  # (d, cap) raw storage-dtype relative coords
-    rel_j: Array,  # (d, cap)
-    shift_i: Array,  # (d, cap) small-int cell shift (cell_now - cell_stale)
-    shift_j: Array,  # (d, cap)
-    off_k,  # (d,) f32 offsets: an array or a list of scalars
-    hc_phys: tuple,  # (d,) static physical cell edges
-) -> tuple[list[Array], Array]:
-    """Shift-anchored physics-tier pair displacement x_i - x_j per axis.
-
-    The half-width force kernel streams the RAW fp16 relative coords
-    plus an int16 per-particle cell shift instead of a pre-shifted fp32
-    coordinate: the stale-binning re-anchor
-    ``rel' = rel + 2 (cell_now - cell_stale)`` happens here in fp32
-    registers — the shift is an exact small integer and fp32 addition of
-    an fp16 payload and a small integer is exact, so the decode is
-    bit-identical to pre-shifting. Everything else matches
-    ``tile_phys_disp``.
-    """
-    d = rel_i.shape[0]
-    disp = []
-    r2 = None
-    for a in range(d):
-        ri = rel_i[a].astype(jnp.float32) + 2.0 * shift_i[a].astype(jnp.float32)
-        rj = rel_j[a].astype(jnp.float32) + 2.0 * shift_j[a].astype(jnp.float32)
-        du = (ri[:, None] - rj[None, :]) * 0.5 - off_k[a]
-        dx = du * hc_phys[a]
-        disp.append(dx)
-        r2 = dx * dx if r2 is None else r2 + dx * dx
-    return disp, r2
-
-
 def tile_occ_pair(occ_i: Array, occ_j: Array) -> Array:
     """(cap_i, cap_j) bool: both slots occupied."""
     return (occ_i[:, None] > 0) & (occ_j[None, :] > 0)
@@ -151,7 +120,8 @@ def bits16_to_f32(bits: Array, dtype) -> Array:
 
 def decode_f32(x: Array, dtype) -> Array:
     """Kernel-side load: 16-bit words of ``dtype`` -> f32, else astype."""
-    if x.dtype in (jnp.int16, jnp.uint16) and jnp.dtype(dtype).kind == "f":
+    if x.dtype in (jnp.int16, jnp.uint16) and jnp.issubdtype(dtype,
+                                                             jnp.floating):
         return bits16_to_f32(x, dtype)
     if x.dtype == jnp.int16:
         return x.astype(jnp.int32).astype(jnp.float32)

@@ -1,5 +1,5 @@
-"""``ops.force_grid_work``: the force kernel's grid steps, launched and
-useful, against a brute-force count over (cell, neighbour cell) pairs."""
+"""``ops.force_grid_work``: the force kernel's (self cell, neighbour
+offset) pairs, launched and useful, against a brute-force count."""
 import itertools
 import types
 
@@ -19,13 +19,16 @@ def _domain(hi, periodic) -> Domain:
 
 
 def _brute(ncells, periodic, counts) -> tuple[int, int]:
-    """Every (cell, neighbour offset) pair; a neighbour off a wall is the
-    empty sentinel cell."""
-    launched = useful = 0
+    """Every (cell, neighbour offset) pair the kernel evaluates: each
+    lane of each row, ghost cells and the padding to 128 lanes included;
+    useful where both cells of a pair hold a particle (a neighbour off a
+    wall is an empty ghost)."""
+    lanes = -(-(ncells[-1] + 2) // 128) * 128
+    launched = int(np.prod(ncells[:-1])) * lanes * 3 ** len(ncells)
+    useful = 0
     grid = np.asarray(counts).reshape(ncells)
     for c in itertools.product(*(range(n) for n in ncells)):
         for off in itertools.product((-1, 0, 1), repeat=len(ncells)):
-            launched += 1
             nb = []
             for x, o, n, p in zip(c, off, ncells, periodic):
                 y = x + o
@@ -69,14 +72,15 @@ def test_full_grid_loses_only_the_sentinel_steps(hi, periodic):
     dom = _domain(hi, periodic)
     C, d = dom.ncells_total, dom.dim
     launched, useful = _work(dom, np.ones(C, np.int32))
-    assert launched == C * 3 ** d
-    # steps whose neighbour lies off a wall: per axis, the cells at a
+    rows, lanes = C // dom.ncells[-1], 128  # rows of under 126 cells
+    assert launched == rows * lanes * 3 ** d
+    # pairs whose neighbour lies off a wall: per axis, the cells at a
     # wall see one offset out of three off the grid
     inside = 1
     for n, p in zip(dom.ncells, periodic):
         inside *= 3 * n if p else 3 * n - 2
     assert useful == inside
-    assert (useful == launched) == all(periodic)
+    assert (useful == C * 3 ** d) == all(periodic)
 
 
 def test_one_empty_cell():
@@ -85,8 +89,8 @@ def test_one_empty_cell():
     counts = np.ones(C, np.int32)
     counts[7] = 0
     launched, useful = _work(dom, counts)
-    # its own 9 steps and the 8 steps of its neighbours that read it
-    assert launched - useful == 9 + 8
+    # its own 9 pairs and the 8 pairs of its neighbours that read it
+    assert C * 9 - useful == 9 + 8
     assert (launched, useful) == _brute(dom.ncells, (True, True), counts)
 
 
@@ -111,25 +115,35 @@ def test_binning_of_particles():
 
 @pytest.mark.parametrize("hi,periodic", [GRIDS[1], GRIDS[4]])
 def test_launched_is_the_grid_the_kernel_is_launched_over(hi, periodic):
+    """``launched`` = grid steps × cells per step × 3^d, read off the
+    ``pallas_call`` the wrapper emits: its grid, and the lanes of its
+    output block (one cell per lane)."""
     from repro.core import scheme
 
     dom = _domain(hi, periodic)
-    d, c1, cap = dom.dim, dom.ncells_total + 1, 8
+    d, cap = dom.dim, 8
+    padded = tuple(n + 2 for n in dom.ncells[:-1])
+    lanes = rcll_force.lane_width(dom.ncells[-1])
 
     def table(rows, dtype):
-        return jnp.zeros((c1, rows, cap), dtype)
+        return jnp.zeros(padded + (rows, cap, lanes), dtype)
 
     jaxpr = jax.make_jaxpr(lambda *a: rcll_force.rcll_force(
-        *a, ncells=dom.ncells, periodic=dom.periodic,
-        hc_phys=dom.cell_sizes, h=dom.h, dim=d,
-        scheme=scheme.wcsph(1.0, 1.0, 0.1), interpret=True,
-    ))(table(d, jnp.float16), table(d, jnp.int16), table(d, jnp.float16),
-       jnp.zeros((c1, cap), jnp.float16), jnp.ones((c1, cap), jnp.float32))
-    grids = [e.params["grid_mapping"].grid for e in _eqns(jaxpr.jaxpr)
+        *a, hc_phys=dom.cell_sizes, h=dom.h, dim=d,
+        rel_dtype=jnp.float16, records_dtype=jnp.float16,
+        scheme=scheme.wcsph(1.0, 1.0, 0.1), cap=cap, interpret=True,
+    ))(table(3 * d, jnp.uint16), table(1, jnp.float32),
+       table(1, jnp.float16))
+    calls = [e.params["grid_mapping"] for e in _eqns(jaxpr.jaxpr)
              if e.primitive.name == "pallas_call"]
-    assert len(grids) == 1
+    assert len(calls) == 1
+    grid = calls[0].grid
+    assert grid == rcll_force.force_grid(dom.ncells)
+    out_block = calls[0].block_mappings[-1].block_aval.shape
+    per_step = out_block[-1]  # lanes; the leading dims are one row
+    assert out_block[:d - 1] == (1,) * (d - 1)
     counts = np.ones(dom.ncells_total, np.int32)
-    assert _work(dom, counts)[0] == int(np.prod(grids[0]))
+    assert _work(dom, counts)[0] == int(np.prod(grid)) * per_step * 3 ** d
 
 
 def _eqns(jaxpr):

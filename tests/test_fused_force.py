@@ -33,31 +33,60 @@ def _poiseuille(backend, *, ds=0.1, skin_frac=0.0, records="fp32", **kw):
     return cfg, st
 
 
-def _cloud_setup(n=800, seed=0, k=256):
+def _cloud_setup(n=800, seed=0, k=256, hi=(1.0, 1.0), periodic=None,
+                 cell_factor=2.0, safety=3.0):
     """Random cloud + packed state + skin-inflated list (no overflow)."""
     rng = np.random.default_rng(seed)
-    ds = (1.0 / n) ** 0.5
-    dom = D.Domain(lo=(0.0, 0.0), hi=(1.0, 1.0), h=1.2 * ds, cell_factor=2.0)
-    x = rng.uniform(0, 1, (n, 2))
+    dim = len(hi)
+    ds = (float(np.prod(hi)) / n) ** (1.0 / dim)
+    dom = D.Domain(lo=(0.0,) * dim, hi=tuple(hi), h=1.2 * ds,
+                   cell_factor=cell_factor,
+                   periodic=periodic or (False,) * dim)
+    x = rng.uniform(0, hi, (n, dim))
     rc = rcll.init_state(dom, dom.normalize(jnp.asarray(x)), jnp.float16)
     cfg = solver.SPHConfig(
         domain=dom, ds=ds, dt=1e-3, max_neighbors=k, algo="rcll",
-        skin=0.5 * min(dom.cell_sizes),
+        skin=0.5 * min(dom.cell_sizes) if cell_factor > 1 else 0.0,
     )
     cfg.validate_skin()
-    cap = cells.default_capacity(dom, n, safety=8.0)
+    cap = cells.default_capacity(dom, n, safety=safety)
     ps = rcll.pack_state(dom, rc, cap)
+    assert int(ps.packing.binning.overflow) == 0
     nl = rcll.packed_neighbors(
         dom, ps, dtype=jnp.float16, compute_dtype=jnp.float32, k=k,
-        radius_cell=cfg.search_radius_cell,
+        radius_cell=cfg.search_radius_cell, window=3**dim * cap,
     )
     assert not bool(nl.overflowed)
     fields = dict(
-        v=jnp.asarray(rng.normal(size=(n, 2)) * 0.1, jnp.float32),
+        v=jnp.asarray(rng.normal(size=(n, dim)) * 0.1, jnp.float32),
         m=jnp.full((n,), 1.0 / n, jnp.float32),
         rho=jnp.asarray(1.0 + 0.01 * rng.normal(size=(n,)), jnp.float32),
     )
     return dom, cfg, ps, nl, fields
+
+
+# Grids of the Pallas kernel's row layout (one grid step per row of
+# cells along the last axis, a ghost cell at each end of every axis,
+# rows padded to 128 lanes): walls on both axes; a periodic slow or fast
+# axis, whose ghosts copy the opposite edge; a fast axis of 130 cells,
+# whose rows span two lane tiles; a 3-D grid.
+GEOMETRIES = {
+    "walls": dict(),
+    "periodic_slow": dict(periodic=(True, False)),
+    "periodic_fast": dict(periodic=(False, True)),
+    "wide_fast": dict(n=2300, hi=(0.26, 11.1), cell_factor=1.0),
+    "3d": dict(n=500, hi=(1.0, 1.0, 1.34), periodic=(False, False, True),
+               cell_factor=1.0),
+}
+
+
+def _wrapped_pairs(dom, ps, nl) -> int:
+    """Pairs of the list whose cells lie at opposite ends of a periodic
+    axis (they interact across the periodic boundary)."""
+    xy = np.asarray(ps.rc.cell_xy)
+    idx = np.minimum(np.asarray(nl.idx), xy.shape[0] - 1)
+    far = np.abs(xy[:, None, :] - xy[idx]) > 1
+    return int(np.sum(np.any(far, -1) & np.asarray(nl.mask)))
 
 
 def _reference_rhs(dom, rc, nl, v, m, rho, *, h, mu, rho0=RHO0, c0=C0):
@@ -90,28 +119,52 @@ def test_fused_xla_rhs_matches_reference():
         np.testing.assert_allclose(acc_f, acc_r, rtol=2e-5, atol=2e-3)
 
 
-def test_fused_pallas_rhs_matches_reference():
+@pytest.mark.parametrize("geometry", list(GEOMETRIES) + ["vmap2"])
+def test_fused_pallas_rhs_matches_reference(geometry):
+    """The kernel against the reference gather path on each grid of
+    ``GEOMETRIES``: a pair across a periodic axis interacts through the
+    ghost copy, and across a wall it does not (the reference's list has
+    such pairs exactly where the axis is periodic). ``vmap2``: a batch
+    of two field sets through ``jax.vmap``, as the ensemble runs it."""
     from repro.kernels import ops
 
-    dom, cfg, ps, nl, f = _cloud_setup()
-    drho_r, acc_r, p = _reference_rhs(
-        dom, ps.rc, nl, f["v"], f["m"], f["rho"], h=dom.h, mu=1.0
-    )
-    drho_k, acc_k = ops.rcll_force_particles(
-        dom, ps.packing.binning, ps.rc, f["v"], f["m"], f["rho"],
-        mu=1.0, c0=C0, rho0=RHO0, interpret=not ON_TPU,
-    )
-    np.testing.assert_allclose(drho_k, drho_r, rtol=2e-5, atol=1e-5)
-    np.testing.assert_allclose(acc_k, acc_r, rtol=2e-5, atol=2e-3)
+    batch = geometry == "vmap2"
+    dom, cfg, ps, nl, f = _cloud_setup(
+        **GEOMETRIES["walls" if batch else geometry])
+    assert (_wrapped_pairs(dom, ps, nl) > 0) == any(dom.periodic)
+
+    def kernel(v, rho):
+        return ops.rcll_force_particles(
+            dom, ps.packing.binning, ps.rc, v, f["m"], rho,
+            mu=1.0, c0=C0, rho0=RHO0, interpret=not ON_TPU,
+        )
+
+    fields = [(f["v"], f["rho"])]
+    if batch:
+        fields.append((-0.5 * f["v"], 2.0 - f["rho"]))
+        drho_b, acc_b = jax.vmap(kernel)(
+            *[jnp.stack(x) for x in zip(*fields)])
+        got = list(zip(drho_b, acc_b))
+    else:
+        got = [kernel(*fields[0])]
+    for (v, rho), (drho_k, acc_k) in zip(fields, got):
+        drho_r, acc_r, _ = _reference_rhs(
+            dom, ps.rc, nl, v, f["m"], rho, h=dom.h, mu=1.0
+        )
+        np.testing.assert_allclose(drho_k, drho_r, rtol=2e-5, atol=1e-5)
+        np.testing.assert_allclose(acc_k, acc_r, rtol=2e-5, atol=2e-3)
 
 
-def test_fused_pallas_stale_binning_with_migrations():
+@pytest.mark.parametrize("geometry", ["walls", "periodic_slow",
+                                      "periodic_fast"])
+def test_fused_pallas_stale_binning_with_migrations(geometry):
     """Between Verlet rebuilds the binning is stale; particles that
-    migrated cells must decode exactly via the int8 shift re-anchor."""
+    migrated cells must decode exactly via the int16 shift re-anchor,
+    across a periodic boundary too (minimum-image shift, ghost copy)."""
     from repro.kernels import ops
 
     rng = np.random.default_rng(3)
-    dom, cfg, ps, nl, f = _cloud_setup(seed=3)
+    dom, cfg, ps, nl, f = _cloud_setup(seed=3, **GEOMETRIES[geometry])
     n = ps.rc.rel.shape[0]
     # displace by < skin/2 in random directions -> boundary-adjacent
     # particles migrate cells while the neighbor list stays valid
@@ -274,19 +327,23 @@ def test_half_records_survive_tiny_masses():
     np.testing.assert_allclose(acc_p, acc32, rtol=2e-3, atol=atol_a)
 
 
-def test_half_records_pallas_matches_xla():
-    """Both half-width backends quantize identically and decode in fp32:
-    they agree to reduction-order round-off."""
+@pytest.mark.parametrize("records", ["fp16", "bf16", "fp32"])
+def test_half_records_pallas_matches_xla(records):
+    """Both backends quantize the records identically and decode in
+    fp32: they agree to reduction-order round-off. bf16 words decode as
+    floats in the kernel (bfloat16's dtype kind is not "f")."""
     from repro.kernels import ops
 
     dom, cfg, ps, nl, f = _cloud_setup(seed=11)
     drho_x, acc_x = fused.force_rhs(
         dom, ps.rc, nl, f["v"], f["m"], f["rho"],
-        c0=C0, rho0=RHO0, mu=1.0, records="fp16",
+        c0=C0, rho0=RHO0, mu=1.0, records=records,
     )
     drho_p, acc_p = ops.rcll_force_particles(
         dom, ps.packing.binning, ps.rc, f["v"], f["m"], f["rho"],
-        mu=1.0, c0=C0, rho0=RHO0, records_dtype=jnp.float16,
+        mu=1.0, c0=C0, rho0=RHO0,
+        records_dtype={"fp16": jnp.float16, "bf16": jnp.bfloat16,
+                       "fp32": jnp.float32}[records],
         interpret=not ON_TPU,
     )
     np.testing.assert_allclose(drho_p, drho_x, rtol=2e-5, atol=1e-5)

@@ -144,14 +144,23 @@ def test_fused_gradient_matches_two_pass():
 # --------------------------------------------------------------------------
 # One-sweep cell-pack kernel vs its jnp oracle
 # --------------------------------------------------------------------------
-@pytest.mark.parametrize("n,dim,seed", [(500, 2, 0), (300, 3, 1)])
-def test_cell_pack_kernel_matches_ref(n, dim, seed):
+@pytest.mark.parametrize("n,dim,seed,periodic", [
+    (500, 2, 0, (False, False)), (300, 3, 1, (False, False, False)),
+    (500, 2, 2, (True, False)), (500, 2, 3, (False, True)),
+])
+def test_cell_pack_kernel_matches_ref(n, dim, seed, periodic):
     """The windowed slab pack equals the per-field id-table gather
-    (``cells.to_cell_major``) plus the sentinel row."""
+    (``cells.to_cell_major``) laid out on the force kernel's rows: every
+    cell of the ghost-padded grid (a periodic ghost copies the opposite
+    edge, a wall ghost is empty), fields leading, slots on the sublanes,
+    cells on the lanes; slots past cap filled as empty, lanes past the
+    row zero."""
+    from repro.kernels import rcll_force
+
     rng = np.random.default_rng(seed)
     ds = (1.0 / n) ** (1.0 / dim)
-    dom = (D.unit_square(h=1.2 * ds) if dim == 2
-           else D.unit_cube(h=1.2 * ds))
+    dom = D.Domain(lo=(0.0,) * dim, hi=(1.0,) * dim, h=1.2 * ds,
+                   periodic=periodic)
     x = rng.uniform(0, 1, (n, dim))
     st = rcll.init_state(dom, dom.normalize(jnp.asarray(x)), jnp.float16)
     cap = cells.default_capacity(dom, n, safety=6.0)
@@ -160,18 +169,26 @@ def test_cell_pack_kernel_matches_ref(n, dim, seed):
     rows16 = jax.lax.bitcast_convert_type(ps.rc.rel, jnp.uint16)
     rows32 = jnp.asarray(rng.normal(size=(n, 2)), jnp.float32)
     fill32 = jnp.asarray([1.0, 0.0], jnp.float32)
-    out_k = ops.cell_tables(rows16, rows32, b.counts, fill32, cap=cap)
-    t16 = cells.to_cell_major(b, rows16).transpose(0, 2, 1)
-    t32 = jnp.stack([
-        cells.to_cell_major(b, rows32[:, f], fill=float(fill32[f]))
-        for f in range(2)], axis=1)
-    out_r = (
-        jnp.concatenate([t16, jnp.zeros((1,) + t16.shape[1:], t16.dtype)]),
-        jnp.concatenate([t32, jnp.broadcast_to(
-            fill32[None, :, None], (1, 2, cap))]),
-    )
-    for a, c in zip(out_k, out_r):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+    out_k = ops.cell_tables(rows16, rows32, b.counts, fill32, cap=cap,
+                            ncells=dom.ncells, periodic=periodic)
+
+    ids = rcll_force.padded_cell_ids(dom.ncells, periodic)
+    cap8 = rcll_force.slot_rows(cap)
+    width = rcll_force.lane_width(dom.ncells[-1])
+    for got, rows, fill in ((out_k[0], rows16, np.zeros(dim)),
+                            (out_k[1], rows32, np.asarray(fill32))):
+        f = rows.shape[1]
+        per_cell = np.stack([
+            np.asarray(cells.to_cell_major(b, rows[:, j], fill=fill[j]))
+            for j in range(f)], axis=-1)  # (C, cap, F)
+        per_cell = np.concatenate(
+            [per_cell, np.broadcast_to(fill, (1, cap, f))])  # empty cell
+        want = np.zeros(ids.shape[:-1] + (f, cap8, width), per_cell.dtype)
+        want[..., :ids.shape[-1]] = fill[:, None, None]
+        # (..., X, cap, F) -> (..., F, cap, X)
+        want[..., :cap, :ids.shape[-1]] = np.moveaxis(
+            per_cell[ids], (-3, -1), (-1, -3))
+        np.testing.assert_array_equal(np.asarray(got), want)
 
 
 # --------------------------------------------------------------------------

@@ -82,31 +82,69 @@ def _case(key):
     return case, dom, cap
 
 
+def _poiseuille_1m():
+    """The 1M Poiseuille channel with a Verlet skin of half the search
+    radius (cells 1.5 radii wide): Morris, Fox & Zhu's channel at ds =
+    1e-3, periodic along the slow axis."""
+    from repro.core import scheme
+    from repro.core.domain import Domain
+
+    dom = Domain(lo=(0.0, -0.003), hi=(1.0, 1.003), h=0.0012,
+                 cell_factor=1.5, periodic=(True, False))
+    assert tuple(dom.ncells) == (277, 280), dom.ncells
+    return dom, scheme.wcsph(0.25, 1.0, 1.0), 41
+
+
+def _wide_rows():
+    """A dam break of rows 2400 cells long: its kernel needs more scoped
+    VMEM than the compiler's default, and asks for it."""
+    from repro.core.domain import Domain
+
+    case, dom, cap = _case("dam_break-1M")
+    hi = (dom.hi[0], dom.lo[1] + 2400 * dom.cell_sizes[1])
+    wide = Domain(lo=dom.lo, hi=hi, h=dom.h, cell_factor=dom.cell_factor)
+    assert wide.ncells[1] == 2400, wide.ncells
+    return wide, case.scheme(), cap
+
+
 @pytest.mark.parametrize("key,records", [
     ("dam_break-1M", "fp16"), ("dam_break-1M", "bf16"),
     ("dam_break-1M", "fp32"), ("dam_break-64k", "fp16"),
-    ("taylor_green-1M", "fp16"),
+    ("taylor_green-1M", "fp16"), ("poiseuille-1M", "fp16"),
+    ("wide-rows", "fp16"),
 ])
 def test_force_kernel_compiles_for_v5e(one_chip, key, records):
-    case, dom, cap = _case(key)
+    if key == "poiseuille-1M":
+        dom, sch, cap = _poiseuille_1m()
+    elif key == "wide-rows":
+        dom, sch, cap = _wide_rows()
+    else:
+        case, dom, cap = _case(key)
+        sch = case.scheme()
     d = dom.dim
-    c1 = dom.ncells_total + 1
     rdt = RECORDS[records]
+    padded = tuple(n + 2 for n in dom.ncells[:-1])
+    tail = (rcll_force.slot_rows(cap), rcll_force.lane_width(dom.ncells[-1]))
+    f16, f32 = rcll_force.slab_fields(jnp.float16, rdt)
+    width = {"inv": 1}
 
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    def spec(rows, dtype):
+        return jax.ShapeDtypeStruct(padded + (rows,) + tail, dtype,
+                                    sharding=one_chip)
 
-    lowered = rcll_force.rcll_force.lower(
-        spec((c1, d, cap), jnp.float16),  # rel: fp16 storage coords
-        spec((c1, d, cap), jnp.int16),  # stale-cell shift
-        spec((c1, d, cap), rdt),  # v
-        spec((c1, cap), rdt),  # m
-        spec((c1, cap), jnp.float32),  # 1/rho
-        ncells=tuple(dom.ncells), periodic=tuple(dom.periodic),
+    traced = rcll_force.rcll_force.trace(
+        spec(sum(width.get(f, d) for f in f16), jnp.uint16),
+        spec(sum(width.get(f, d) for f in f32), jnp.float32),
+        spec(1, rdt),  # m
         hc_phys=tuple(dom.cell_sizes), h=dom.h, dim=d,
-        scheme=case.scheme(), interpret=False,
+        rel_dtype=jnp.float16, records_dtype=rdt, scheme=sch, cap=cap,
+        interpret=False,
     )
-    compiled = lowered.compile()
+    grids = [e.params["grid_mapping"].grid for e in traced.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(grids) == 1
+    assert np.prod(grids[0]) <= dom.ncells_total / 100, grids
+    compiled = traced.lower().compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
 
@@ -120,7 +158,8 @@ def test_cell_pack_compiles_for_v5e(one_chip, key):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pack = jax.jit(functools.partial(ops.cell_tables, cap=cap))
+    pack = jax.jit(functools.partial(
+        ops.cell_tables, cap=cap, ncells=grid, periodic=(False,) * d))
     compiled = pack.lower(
         spec((N, 3 * d), jnp.uint16),  # [rel | shift | v] 16-bit slab
         spec((N, 1), jnp.float32),  # [1/rho] fp32 slab
@@ -167,7 +206,12 @@ def test_step_scopes_survive_the_tpu_compile(one_chip, monkeypatch):
     monkeypatch.setattr(ops, "default_interpret", lambda: False)
     specs = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=one_chip), carry)
-    text = solver.run_persistent.lower(cfg, specs, 2).compile().as_text()
+    try:
+        text = solver.run_persistent.lower(cfg, specs, 2).compile().as_text()
+    finally:
+        # the trace holds the compiled kernel (interpret=False): keep it
+        # from a later CPU lowering of the same step in this process
+        jax.clear_caches()
     comps = _computations(text)
     kernel = [p for ins in comps.values() for op, p, _ in ins
               if op == "custom-call" and p.endswith("rcll_force/pallas_call")]
